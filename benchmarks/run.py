@@ -11,6 +11,8 @@ import time
 
 def main() -> None:
     t0 = time.time()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (adapter_swap, batched_lora_micro, paged_kv,
                             prefill_batching, prefix_cache, router_bench,
                             serving_tables, slo_scheduling)

@@ -112,10 +112,12 @@ def sgmv(x: jax.Array, a_stack: jax.Array, b_stack: jax.Array,
     # gather into padded u-batch layout (the paper's Fig. 6 gather)
     xbuf = jnp.zeros((plan.n_padded, d_in), x.dtype)
     xbuf = xbuf.at[plan.padded_pos].set(x[plan.perm])
+    # each kernel fits blk_d to its own lane axis (d_in for shrink, d_out
+    # for expand), see sgmv._fit
     s = sgmv_shrink(xbuf, a_stack, plan.block_slots, blk_t=blk_t,
-                    blk_d=min(blk_d, d_in), interpret=interpret)
+                    blk_d=blk_d, interpret=interpret)
     y = sgmv_expand(s, b_stack, plan.block_slots, blk_t=blk_t,
-                    blk_d=min(blk_d, b_stack.shape[1]), interpret=interpret)
+                    blk_d=blk_d, interpret=interpret)
     # scatter back to original order (Fig. 6 scatter)
     y_sorted = y[plan.padded_pos]
     out = jnp.zeros((t, b_stack.shape[1]), y.dtype).at[plan.perm].set(y_sorted)

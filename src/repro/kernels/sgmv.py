@@ -12,7 +12,8 @@ unique adapter (Punica-style SGMV on GPU). The TPU-native formulation:
 * the d dimension is tiled (``blk_d``) with an f32 VMEM accumulator so the
   working set fits VMEM for d_ff-sized projections (up to 49k here).
 
-MXU alignment: blk_t/blk_d are multiples of 128; the LoRA rank r (16/32)
+MXU alignment: blk_d is a multiple of 128 (or the whole width, see
+``_fit``) and blk_t a multiple of 8; the LoRA rank r (16/32)
 rides the sublane dimension (multiple of 8), so tiles are well-formed —
 the rank<128 lane waste in the expand GEMM is real and is reported in the
 roofline "useful FLOPs" ratio rather than hidden.
@@ -31,15 +32,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLK_T = 128
 DEFAULT_BLK_D = 512
+LANE = 128
 
 
 def _fit(n: int, requested: int) -> int:
-    """Largest divisor of n ≤ requested (keeps BlockSpecs well-formed for
-    non-power-of-two projection widths)."""
-    b = min(requested, n)
-    while n % b:
-        b -= 1
-    return b
+    """Lane-dim tile for a width-n axis: the largest multiple of 128 that
+    divides n and is ≤ requested, else n itself. The TPU lowering accepts
+    a block's last dim only if it is a multiple of 128 or the whole axis
+    (qwen2-0.5b's 896 and 4864 give 128 and 256, not 448 and 304)."""
+    for b in range(min(requested, n) // LANE * LANE, 0, -LANE):
+        if n % b == 0:
+            return b
+    return n
 
 
 def _shrink_kernel(slots_ref, x_ref, a_ref, o_ref, acc_ref, *, nd: int):

@@ -4,23 +4,29 @@
         --reduced --n-adapters 64 --rate 2.0 --duration 30 \
         --policy edgelora
 
-On this CPU container ``--reduced`` (tiny same-family variant) is the
-practical default; the full configs are exercised via the dry-run. The
-launcher wires workload → engine → metrics and prints a paper-style
-summary row.
+``--reduced`` (the default: a tiny same-family variant, 2 layers at
+d_model 256) is what CPU runs and tests use. ``--full`` runs the
+architecture at its published widths, which is what the chip serves
+(``chip_smoke.py`` drives it that way on one TPU). The launcher wires
+workload → engine → metrics and prints a paper-style summary row.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
+from typing import List, Tuple
 
 from repro.configs import get_config, reduced_config
+from repro.core.slots import Request
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EdgeLoRAEngine, EngineConfig, OutOfMemoryError
+from repro.serving.trace import EngineTracer
 from repro.serving.workload import WorkloadConfig, generate_trace
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -148,12 +154,16 @@ def main(argv=None) -> int:
                          "summary are bit-identical with or without it")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", action="store_true")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build(args: argparse.Namespace) -> Tuple[List[Request], EdgeLoRAEngine]:
+    """Model config, synthetic trace and engine for parsed CLI ``args``
+    (the engine raises ``OutOfMemoryError`` when the llamacpp policy's
+    preload exceeds the budget)."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
-    import dataclasses
     cfg = dataclasses.replace(
         cfg, lora=dataclasses.replace(cfg.lora, n_adapters=args.n_adapters))
 
@@ -190,16 +200,20 @@ def main(argv=None) -> int:
         disk_bandwidth=args.disk_bandwidth,
         prefill_batching=args.prefill_batching,
         router_batching=args.router_batching, seed=args.seed)
-    tracer = None
-    if args.trace:
-        from repro.serving.trace import EngineTracer
-        tracer = EngineTracer()
+    tracer = EngineTracer() if args.trace else None
+    return trace, EdgeLoRAEngine(cfg, ecfg, tracer=tracer)
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     try:
-        engine = EdgeLoRAEngine(cfg, ecfg, tracer=tracer)
+        trace, engine = build(args)
     except OutOfMemoryError as e:
         print(f"OOM: {e}")
         return 2
     summary = engine.serve(trace)
+    tracer = engine.tracer
     if tracer is not None:
         tracer.export(args.trace)
         print(f"# trace written to {args.trace} "

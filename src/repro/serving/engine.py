@@ -325,6 +325,8 @@ class EdgeLoRAEngine:
         backend, interpret = self.lora_backend, self._sgmv_interpret
         self.prefix_enabled = False
         self.prefix_cache: Optional[PrefixCache] = None
+        # the paged decode's page fetch (None: the jnp gather)
+        self.page_gather: Optional[Callable[..., Any]] = None
         chunk = self.ecfg.prefill_chunk
         if chunk is not None and chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1 (or None to "
@@ -477,12 +479,12 @@ class EdgeLoRAEngine:
         use_kernel = ecfg.kv_gather_kernel
         if use_kernel is None:  # only where it pays: real TPU
             use_kernel = jax.default_backend() == "tpu"
-        page_gather = None
         if use_kernel:
             from repro.kernels.ops import paged_gather
-            page_gather = functools.partial(
+            self.page_gather = functools.partial(
                 paged_gather, interpret=jax.default_backend() != "tpu",
                 use_kernel=True)
+        page_gather = self.page_gather
 
         def paged_decode_fn(params: Any, pool: Any, tokens: Any, cache: Any,
                             tables: Any, lengths: Any, prompt_lens: Any,

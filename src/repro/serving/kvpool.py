@@ -31,12 +31,8 @@ from __future__ import annotations
 from dataclasses import asdict, dataclass
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
+import jax.numpy as jnp
 import numpy as np
-
-try:  # device-side face; the numpy allocator stays importable without jax
-    import jax.numpy as jnp
-except ImportError:  # pragma: no cover - container always has jax
-    jnp = None
 
 
 class OutOfBlocksError(RuntimeError):

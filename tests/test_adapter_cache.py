@@ -1,9 +1,6 @@
 """Heterogeneous memory manager: LRU/LFU + pool invariants (hypothesis)."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.adapter_cache import AdapterMemoryManager
 

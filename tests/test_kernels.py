@@ -5,13 +5,11 @@ executes the kernel body exactly)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
+from repro.kernels.sgmv import _fit
 
 
 # ---------------------------------------------------------------------------
@@ -24,6 +22,10 @@ from repro.kernels.decode_attention import flash_decode
     (64, 256, 512, 16, 4, 16, 128),
     (37, 384, 256, 32, 5, 8, 128),   # ragged T
     (128, 512, 384, 16, 8, 32, 256),
+    # qwen2-0.5b widths: 896 → 128-wide lane tiles, 4864 → 256-wide
+    (24, 896, 896, 16, 4, None, 512),
+    (24, 896, 4864, 16, 4, None, 512),
+    (24, 4864, 896, 16, 4, None, 512),
 ])
 def test_sgmv_vs_ref(dtype, t, d_in, d_out, r, n_slots, blk_t, blk_d):
     rng = np.random.default_rng(42)
@@ -89,6 +91,19 @@ def test_sgmv_hypothesis_allclose(t, n_slots, seed):
     y_ref = ref.sgmv_ref(x, a, b, slots, 1.0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=2e-5,
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("n,requested", [
+    (896, 512), (4864, 512), (128, 512), (256, 256), (384, 128),
+    (512, 512), (64, 512), (896, 64), (1000, 512), (14336, 512)])
+def test_fit_lane_tile(n, requested):
+    """The lane tile is a multiple of 128 dividing n and ≤ the request,
+    the largest such, or else the whole width (what the TPU lowering
+    accepts for a block's last dim)."""
+    b = _fit(n, requested)
+    fits = [m for m in range(128, min(n, requested) + 1, 128) if n % m == 0]
+    assert b == (max(fits) if fits else n)
+    assert n % b == 0 and (b % 128 == 0 or b == n)
 
 
 # ---------------------------------------------------------------------------

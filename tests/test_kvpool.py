@@ -1,10 +1,7 @@
 """Paged KV pool: allocation invariants (hypothesis) + gather reference."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.kvpool import (OutOfBlocksError, PagedKVPool, gather_kv,
                                   write_kv)

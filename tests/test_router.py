@@ -1,9 +1,6 @@
 """Adaptive Adapter Selection (Algorithm 1) properties."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.adapter_cache import AdapterMemoryManager
 from repro.core.router import OracleRouter, select_adapter
