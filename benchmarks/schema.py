@@ -109,6 +109,42 @@ def _check_chrome_events(events: Any, name: str) -> List[str]:
     return errors
 
 
+def _check_host_spans(spans: Any, where: str) -> List[str]:
+    """Host spans: ``[id, parent_id, name, t0_ns, t1_ns, args]`` in
+    start order, ids equal to positions, each closed and inside its
+    parent's interval (an empty list: spans were off)."""
+    if not isinstance(spans, list):
+        return [f"{where}: missing 'spans' list"]
+    errors: List[str] = []
+    for i, rec in enumerate(spans):
+        sw = f"{where}.spans[{i}]"
+        if not isinstance(rec, list) or len(rec) != 6:
+            errors.append(f"{sw}: not a 6-item list")
+            continue
+        sid, parent, name, t0, t1, args = rec
+        if sid != i:
+            errors.append(f"{sw}: id {sid!r} != position")
+        if not isinstance(name, str) or not name:
+            errors.append(f"{sw}: missing/empty name")
+        if not isinstance(args, dict):
+            errors.append(f"{sw}: args not a dict")
+        if not (_is_number(t0) and _is_number(t1)
+                and math.isfinite(t0) and math.isfinite(t1) and t1 >= t0):
+            errors.append(f"{sw}: bad interval [{t0!r}, {t1!r}]")
+            continue
+        if parent is None:
+            continue
+        if not isinstance(parent, int) or not 0 <= parent < i:
+            errors.append(f"{sw}: bad parent {parent!r}")
+            continue
+        outer = spans[parent]
+        if (isinstance(outer, list) and len(outer) == 6
+                and _is_number(outer[3]) and _is_number(outer[4])
+                and not outer[3] <= t0 <= t1 <= outer[4]):
+            errors.append(f"{sw}: interval escapes parent {parent}")
+    return errors
+
+
 def _check_trace_section(section: Any, name: str) -> List[str]:
     errors: List[str] = []
     where = f"{name}.edgelora"
@@ -136,6 +172,7 @@ def _check_trace_section(section: Any, name: str) -> List[str]:
             for field in ("kind", "track", "name"):
                 if not isinstance(ev.get(field), str) or not ev.get(field):
                     errors.append(f"{ew}: missing/empty '{field}'")
+    errors.extend(_check_host_spans(section.get("spans"), where))
     metrics = section.get("metrics")
     if not isinstance(metrics, dict):
         errors.append(f"{where}: missing 'metrics' dict")
@@ -174,9 +211,10 @@ def validate_trace_json(data: Any, name: str = "<trace>") -> List[str]:
     Contract (see docs/observability.md): a Chrome-trace object with a
     non-empty ``traceEvents`` list of well-formed events (known phases,
     finite non-negative timestamps/durations) plus an ``edgelora``
-    section carrying the raw event log, metrics series, per-request
-    latency breakdowns whose segments sum to e2e, and the watchdog
-    report. Returns violations (empty == valid).
+    section carrying the raw event log, the host spans (closed, nested
+    inside their parents), metrics series, per-request latency
+    breakdowns whose segments sum to e2e, and the watchdog report.
+    Returns violations (empty == valid).
     """
     if not isinstance(data, dict):
         got = type(data).__name__

@@ -111,7 +111,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.adapter_cache import AdapterMemoryManager, PoolExhaustedError
+from repro.core.adapter_cache import (AdapterMemoryManager, PoolExhaustedError,
+                                      Reservation)
 from repro.core.lora import LoRAMode, resolve_lora_exec
 from repro.core.router import OracleRouter, select_adapter
 from repro.core.slots import Request, Slot, SlotManager, SlotState
@@ -120,7 +121,7 @@ from repro.serving import kvpool as kvlib
 from repro.serving.kvpool import PagedKVPool
 from repro.serving.metrics import ServingSummary, summarize
 from repro.serving.prefix_cache import PrefixCache
-from repro.serving.trace import (EngineTracer, JitRecompileError,
+from repro.serving.trace import (NO_SPAN, EngineTracer, JitRecompileError,
                                  jit_cache_report)
 
 
@@ -305,15 +306,27 @@ class EdgeLoRAEngine:
         """Device-side pool write. The *cost* of the transfer is not
         charged here: the manager books it on its transfer channel and
         returns it on the reservation (the old ``_pending_load_cost``
-        side-channel, retired)."""
-        adapter = self._adapter_host(adapter_id)
-        new_pool = {}
-        for key, sub in self.lora_pool.items():
-            ax = self._LEAD_AXIS[key]
-            new_pool[key] = jax.tree.map(
-                lambda p, a, ax=ax: jax.lax.dynamic_update_index_in_dim(
-                    p, a.astype(p.dtype), slot, axis=ax), sub, adapter[key])
-        self.lora_pool = new_pool
+        side-channel, retired). Traced, the host side of the write (fetch
+        and dispatch) is the ``adapter.load`` span: ``bytes`` is the
+        adapter's payload, ``pool_bytes`` the pool arrays the write
+        replaces."""
+        tr = self.tracer
+        if tr is not None:
+            tr.metrics.counter("load.bytes").inc(self.adapter_bytes)
+        with (tr.span("adapter.load", adapter=adapter_id, slot=slot,
+                      bytes=self.adapter_bytes,
+                      pool_bytes=sum(p.nbytes for p in
+                                     jax.tree.leaves(self.lora_pool)))
+              if tr is not None else NO_SPAN):
+            adapter = self._adapter_host(adapter_id)
+            new_pool = {}
+            for key, sub in self.lora_pool.items():
+                ax = self._LEAD_AXIS[key]
+                new_pool[key] = jax.tree.map(
+                    lambda p, a, ax=ax: jax.lax.dynamic_update_index_in_dim(
+                        p, a.astype(p.dtype), slot, axis=ax), sub,
+                    adapter[key])
+            self.lora_pool = new_pool
 
     # ------------------------------------------------------------------
     # jit'd compute steps
@@ -620,24 +633,27 @@ class EdgeLoRAEngine:
         (first call per key also as a jit-compile event — the recompile
         watchdog's raw signal); ``requests`` names the real group members
         the span served (padding replicas excluded)."""
+        tr = self.tracer
         warm = key not in self._durations
         if warm:
             out = fn(*args)  # compile + run (warmup, uncharged)
-            jax.block_until_ready(out)
+            with tr.span("wait", key=key) if tr is not None else NO_SPAN:
+                jax.block_until_ready(out)
             t0 = time.perf_counter()  # el: allow[clock] -- _timed measures
             out = fn(*args)
-            jax.block_until_ready(out)
+            with tr.span("wait", key=key) if tr is not None else NO_SPAN:
+                jax.block_until_ready(out)
             self._durations[key] = (
                 time.perf_counter() - t0)  # el: allow[clock] -- _timed
         else:
             t0 = time.perf_counter()  # el: allow[clock] -- _timed measures
             out = fn(*args)
-            jax.block_until_ready(out)
+            with tr.span("wait", key=key) if tr is not None else NO_SPAN:
+                jax.block_until_ready(out)
             self._durations[key] = 0.5 * self._durations[key] + 0.5 * (
                 time.perf_counter() - t0)  # el: allow[clock] -- _timed
         dt = self._durations[key] * self.ecfg.time_scale
         self.busy_time += dt
-        tr = self.tracer
         if tr is not None and now is not None:
             if warm:
                 tr.compile(now, key)
@@ -650,6 +666,13 @@ class EdgeLoRAEngine:
 
     def serve(self, trace: List[Request],
               max_sim_time: Optional[float] = None) -> ServingSummary:
+        tr = self.tracer
+        with (tr.span("serve", n_requests=len(trace))
+              if tr is not None else NO_SPAN):
+            return self._serve(trace, max_sim_time)
+
+    def _serve(self, trace: List[Request],
+               max_sim_time: Optional[float]) -> ServingSummary:
         ecfg = self.ecfg
         for r in trace:
             if r.prompt_len > ecfg.max_ctx:
@@ -743,402 +766,423 @@ class EdgeLoRAEngine:
                 return bool(self._ready)
 
             while len(completed) + len(rejected) < len(queue):
-                if max_sim_time is not None and now > max_sim_time:
-                    break
-                progressed = False
-                busy0 = self.busy_time
-
-                # ---- admission -------------------------------------------
-                idle = self.slots.idle()
-                if ecfg.policy == "dlora" and idle and arrivals_ready():
-                    want_mode, want_adapter = dlora_desired()
-                    if (want_mode, want_adapter) != (dlora_mode,
-                                                     dlora_merged_adapter):
-                        if self.slots.any_active:
-                            idle = []  # drain before switching modes
-                        else:
-                            # unmerge old and/or merge new: weight-sized traffic
-                            cost = 0.0
-                            if dlora_merged_adapter is not None:
-                                cost += 2 * self.adapter_bytes / ecfg.mem_bandwidth
-                            if want_adapter is not None:
-                                cost += 2 * self.adapter_bytes / ecfg.mem_bandwidth
-                            now += cost
-                            dlora_mode, dlora_merged_adapter = (want_mode,
-                                                                want_adapter)
-                            if tr is not None:
-                                tr.sched(now, "merge_switch", mode=want_mode,
-                                         adapter=want_adapter, cost=cost)
-                while idle and arrivals_ready():
-                    req = self._ready[0][3]
-                    if ecfg.admission_control and req.ttft_slo is not None \
-                            and self._reject_expired(req, now, rejected):
-                        progressed = True
-                        continue  # next heap head (rejection IS progress)
-                    if ecfg.policy == "dlora" and dlora_mode == "merged" \
-                            and req.true_adapter != dlora_merged_adapter:
-                        break  # merged mode serves only the folded adapter
-                    if self.paged and not self.kvpool.can_allocate(
-                            req.prompt_len + 1):
-                        # KV arena exhausted: OutOfBlocks feeds the same
-                        # deferral discipline as adapter-pool exhaustion —
-                        # leave the request queued and retry once a
-                        # completion (or preemption) frees pages. Checked
-                        # *before* any merge-cost accounting so a deferred
-                        # admission charges nothing. +1: the first decode
-                        # write must never OOM right after admission.
-                        self.kv_deferrals += 1
-                        if tr is not None:
-                            tr.sched(now, "defer_kv", request=req)
+                with tr.span("iter") if tr is not None else NO_SPAN:
+                    if max_sim_time is not None and now > max_sim_time:
                         break
-                    if ecfg.policy == "llamacpp":
-                        want = req.true_adapter
-                        if active_adapter is None:
-                            active_adapter = want
-                            # merge the adapter into the base weights
-                            now += 2 * self.adapter_bytes / ecfg.mem_bandwidth
-                            if tr is not None:
-                                tr.sched(now, "merge_switch", adapter=want)
-                        if want != active_adapter:
-                            if self.slots.any_active:
-                                break  # must drain before switching adapters
-                            # unmerge old + merge new
-                            now += 4 * self.adapter_bytes / ecfg.mem_bandwidth
-                            active_adapter = want
-                            if tr is not None:
-                                tr.sched(now, "merge_switch", adapter=want)
-                    heapq.heappop(self._ready)
-                    slot = idle.pop()
-                    slot.assign(req)
-                    req.admit_time = now
-                    slot.admit_seq = self._admit_counter
-                    self._admit_counter += 1
-                    if tr is not None:
-                        tr.sched(now, "admit", request=req, slot=slot.index)
-                        tr.transition(now, slot.index, "idle", "selecting",
-                                      req)
-                    if self.paged:
-                        self.kvpool.register(req.request_id)
-                        key = (self._admission_exec_key(req, dlora_mode)
-                               if self.prefix_enabled else None)
-                        if key is not None:
-                            # execution identity known at admission: splice
-                            # cached prefix pages now and allocate only the
-                            # suffix (the +1 gate headroom covers the COW
-                            # page, so this cannot OOM)
-                            slot.prefix_len = self._admit_prefix(req, key)
-                        else:
-                            # AAS-routed request: adapter unknown until
-                            # selection — reserve the full prompt and swap
-                            # in shared pages at SELECTING→PREFILL
-                            self.kvpool.append_tokens(req.request_id,
-                                                      req.prompt_len)
-                    progressed = True
-                self.peak_active_slots = max(
-                    self.peak_active_slots,
-                    sum(s.state != SlotState.IDLE for s in self.slots.slots))
+                    progressed = False
+                    busy0 = self.busy_time
 
-                # ---- adapter selection (Algorithm 1) ---------------------
-                # batched router scoring: every SELECTING slot that needs a
-                # learned-router forward is scored in one scores_batch call
-                # per prompt bucket (same gather→batch trick as prefill);
-                # scores land in slot.sel_scores exactly as the solo path
-                # caches them, so pool-exhausted deferral semantics below are
-                # unchanged
-                if (ecfg.router_batching
-                        and ecfg.policy not in ("dlora", "llamacpp",
-                                                "edgelora_no_aas")
-                        and getattr(self.router, "costs_forward", False)):
-                    unscored = [
-                        s for s in self.slots.in_state(SlotState.SELECTING)
-                        if s.sel_scores is None and s.request.adapter_id is None]
-                    score_groups: Dict[int, List[Slot]] = {}
-                    for slot in unscored:
-                        self._slot_prompt(slot)
-                        score_groups.setdefault(slot.bucket, []).append(slot)
-                    for b, group in score_groups.items():
-                        rows = self._pad_group(group)
-                        toks = jnp.stack([s.padded_prompt for s in rows])
-                        rids = ([s.request.request_id for s in group]
-                                if tr is not None else None)
-                        sb, dt = self._timed(("router", b, len(rows)),
-                                             self.router.scores_batch, toks,
-                                             now=now, requests=rids)
-                        now += dt
-                        self.router_steps += 1
-                        sb = np.asarray(sb)  # el: allow[host-sync] -- host argmax
-                        for i, slot in enumerate(group):
-                            slot.sel_scores = sb[i]
-                for slot in self.slots.in_state(SlotState.SELECTING):
-                    req = slot.request
-                    if ecfg.policy == "dlora":
-                        req.selected_adapter = req.true_adapter
-                        slot.merged = dlora_mode == "merged"
-                        if not slot.merged:
-                            try:
-                                res = self.manager.acquire(
-                                    req.selected_adapter, now=now)
-                            except PoolExhaustedError:
+                    # ---- admission -------------------------------------------
+                    admitted: Optional[List[int]] = ([] if tr is not None
+                                                      else None)
+                    with (tr.span("admit", requests=admitted)
+                          if tr is not None else NO_SPAN):
+                        idle = self.slots.idle()
+                        if ecfg.policy == "dlora" and idle and arrivals_ready():
+                            want_mode, want_adapter = dlora_desired()
+                            if (want_mode, want_adapter) != (dlora_mode,
+                                                             dlora_merged_adapter):
+                                if self.slots.any_active:
+                                    idle = []  # drain before switching modes
+                                else:
+                                    # unmerge old and/or merge new: weight-sized traffic
+                                    cost = 0.0
+                                    if dlora_merged_adapter is not None:
+                                        cost += (2 * self.adapter_bytes
+                                                 / ecfg.mem_bandwidth)
+                                    if want_adapter is not None:
+                                        cost += (2 * self.adapter_bytes
+                                                 / ecfg.mem_bandwidth)
+                                    now += cost
+                                    dlora_mode, dlora_merged_adapter = (want_mode,
+                                                                        want_adapter)
+                                    if tr is not None:
+                                        tr.sched(now, "merge_switch", mode=want_mode,
+                                                 adapter=want_adapter, cost=cost)
+                        while idle and arrivals_ready():
+                            req = self._ready[0][3]
+                            if ecfg.admission_control and req.ttft_slo is not None \
+                                    and self._reject_expired(req, now, rejected):
+                                progressed = True
+                                continue  # next heap head (rejection IS progress)
+                            if ecfg.policy == "dlora" and dlora_mode == "merged" \
+                                    and req.true_adapter != dlora_merged_adapter:
+                                break  # merged mode serves only the folded adapter
+                            if self.paged and not self.kvpool.can_allocate(
+                                    req.prompt_len + 1):
+                                # KV arena exhausted: OutOfBlocks feeds the same
+                                # deferral discipline as adapter-pool exhaustion —
+                                # leave the request queued and retry once a
+                                # completion (or preemption) frees pages. Checked
+                                # *before* any merge-cost accounting so a deferred
+                                # admission charges nothing. +1: the first decode
+                                # write must never OOM right after admission.
+                                self.kv_deferrals += 1
                                 if tr is not None:
-                                    tr.sched(now, "defer_pool", request=req)
-                                continue  # pool fully pinned: defer (see below)
-                            now = self._finish_acquire(slot, res, now)
-                        else:
-                            slot.adapter_slot = 0
-                            slot.state = SlotState.PREFILL
+                                    tr.sched(now, "defer_kv", request=req)
+                                break
+                            if ecfg.policy == "llamacpp":
+                                want = req.true_adapter
+                                if active_adapter is None:
+                                    active_adapter = want
+                                    # merge the adapter into the base weights
+                                    now += 2 * self.adapter_bytes / ecfg.mem_bandwidth
+                                    if tr is not None:
+                                        tr.sched(now, "merge_switch", adapter=want)
+                                if want != active_adapter:
+                                    if self.slots.any_active:
+                                        break  # must drain before switching adapters
+                                    # unmerge old + merge new
+                                    now += 4 * self.adapter_bytes / ecfg.mem_bandwidth
+                                    active_adapter = want
+                                    if tr is not None:
+                                        tr.sched(now, "merge_switch", adapter=want)
+                            heapq.heappop(self._ready)
+                            slot = idle.pop()
+                            slot.assign(req)
+                            req.admit_time = now
+                            slot.admit_seq = self._admit_counter
+                            self._admit_counter += 1
                             if tr is not None:
-                                tr.transition(now, slot.index, "selecting",
-                                              "prefill", req)
-                        progressed = True
-                        continue
-                    slot.merged = False
-                    if ecfg.policy == "llamacpp":
-                        # baseline executes MERGED: the active adapter was
-                        # folded into W at admission (cost charged there), so
-                        # steps must skip LoRA math entirely — running the
-                        # batched path with adapter_slot=0 would silently
-                        # apply whatever adapter sits in pool slot 0
-                        req.selected_adapter = req.true_adapter
-                        slot.merged = True
-                    elif ecfg.policy == "edgelora_no_aas" or req.adapter_id is not None:
-                        # explicit adapter: bypass adaptive selection (Alg 1 l.1)
-                        req.selected_adapter = (req.adapter_id
-                                                if req.adapter_id is not None
-                                                else req.true_adapter)
-                    else:
-                        # scores are computed (and, for a learned router,
-                        # charged) once per request and cached on the slot: a
-                        # pool-exhausted deferral below must not re-roll the
-                        # oracle RNG or re-charge a router forward on retry
-                        scores = slot.sel_scores
-                        if scores is None:
-                            if getattr(self.router, "costs_forward", False):
-                                # solo fallback (router_batching off): one
-                                # router forward ≈ one prompt pass (Table 6)
-                                toks = self._slot_prompt(slot)[None, :]
-                                rids = ([req.request_id]
+                                tr.sched(now, "admit", request=req, slot=slot.index)
+                                tr.transition(now, slot.index, "idle", "selecting",
+                                              req)
+                            if admitted is not None:
+                                admitted.append(req.request_id)
+                            if self.paged:
+                                self.kvpool.register(req.request_id)
+                                key = (self._admission_exec_key(req, dlora_mode)
+                                       if self.prefix_enabled else None)
+                                if key is not None:
+                                    # execution identity known at admission: splice
+                                    # cached prefix pages now and allocate only the
+                                    # suffix (the +1 gate headroom covers the COW
+                                    # page, so this cannot OOM)
+                                    slot.prefix_len = self._admit_prefix(req, key)
+                                else:
+                                    # AAS-routed request: adapter unknown until
+                                    # selection — reserve the full prompt and swap
+                                    # in shared pages at SELECTING→PREFILL
+                                    self.kvpool.append_tokens(req.request_id,
+                                                              req.prompt_len)
+                            progressed = True
+                    self.peak_active_slots = max(
+                        self.peak_active_slots,
+                        sum(s.state != SlotState.IDLE for s in self.slots.slots))
+
+                    # ---- adapter selection (Algorithm 1) ---------------------
+                    # batched router scoring: every SELECTING slot that needs a
+                    # learned-router forward is scored in one scores_batch call
+                    # per prompt bucket (same gather→batch trick as prefill);
+                    # scores land in slot.sel_scores exactly as the solo path
+                    # caches them, so pool-exhausted deferral semantics below are
+                    # unchanged
+                    with tr.span("select") if tr is not None else NO_SPAN:
+                        if (ecfg.router_batching
+                                and ecfg.policy not in ("dlora", "llamacpp",
+                                                        "edgelora_no_aas")
+                                and getattr(self.router, "costs_forward", False)):
+                            unscored = [
+                                s for s in self.slots.in_state(SlotState.SELECTING)
+                                if s.sel_scores is None
+                                and s.request.adapter_id is None]
+                            score_groups: Dict[int, List[Slot]] = {}
+                            for slot in unscored:
+                                self._slot_prompt(slot)
+                                score_groups.setdefault(slot.bucket, []).append(slot)
+                            for b, group in score_groups.items():
+                                rows = self._pad_group(group)
+                                toks = jnp.stack([s.padded_prompt for s in rows])
+                                rids = ([s.request.request_id for s in group]
                                         if tr is not None else None)
-                                sb, dt = self._timed(("router", slot.bucket, 1),
-                                                     self.router.scores_batch,
-                                                     toks, now=now,
-                                                     requests=rids)
+                                sb, dt = self._timed(("router", b, len(rows)),
+                                                     self.router.scores_batch, toks,
+                                                     now=now, requests=rids)
                                 now += dt
                                 self.router_steps += 1
-                                scores = np.asarray(sb)[0]  # el: allow[host-sync]
-                            else:
-                                scores = np.asarray(self.router.scores(req))
-                            slot.sel_scores = scores
-                        # re-select from cached scores each attempt: the pool
-                        # contents change while deferred, so a cached top-k
-                        # adapter may become acquirable (Algorithm 1 intent)
-                        aid, _ = select_adapter(scores, self.manager,
-                                                ecfg.top_k)
-                        req.selected_adapter = aid
-                    if ecfg.policy != "llamacpp":
-                        try:
-                            res = self.manager.acquire(
-                                req.selected_adapter, now=now)
-                        except PoolExhaustedError:
-                            # every pool block is pinned by an in-flight
-                            # request (γ > R under adapter-diverse load):
-                            # leave the slot SELECTING and retry after a
-                            # completion unpins — pins are only held by
-                            # LOADING/PREFILL/GENERATE slots, so the loop
-                            # always progresses elsewhere
-                            if tr is not None:
-                                tr.sched(now, "defer_pool", request=req)
-                            continue
-                        slot.sel_scores = None
-                        now = self._finish_acquire(slot, res, now)
-                    else:
-                        slot.sel_scores = None
-                        slot.adapter_slot = 0  # merged weights: adapter rides W
-                        slot.state = SlotState.PREFILL
-                        if tr is not None:
-                            tr.transition(now, slot.index, "selecting",
-                                          "prefill", req)
-                    if self.prefix_enabled and \
-                            self._admission_exec_key(req, dlora_mode) is None:
-                        # AAS-routed: the adapter was unknown at admission —
-                        # match now and swap shared pages into the reserved
-                        # table (capacity accounting stays conservative)
-                        self._attach_prefix(slot)
-                    progressed = True
-
-                # ---- async swap-in: transfers that have landed ------------
-                if ecfg.async_swap:
-                    for slot in self.slots.in_state(SlotState.LOADING):
-                        if slot.ready_time <= now:
-                            slot.state = SlotState.PREFILL
-                            if tr is not None:
-                                tr.transition(now, slot.index, "loading",
-                                              "prefill", slot.request)
-                            progressed = True
-                    # queue-ahead prefetch: start transfers for upcoming
-                    # demand while the channel would otherwise sit idle
-                    # (behind any demand loads booked this tick)
-                    if ecfg.prefetch_depth > 0 and ecfg.policy != "llamacpp":
-                        self._run_prefetch(now, dlora_mode)
-
-                # ---- prefill (gather→batch→scatter) ----------------------
-                prefilling = self.slots.in_state(SlotState.PREFILL)
-                if prefilling:
-                    # group same-bucket slots (split by merged-ness: merged
-                    # steps skip LoRA math entirely — and by prefix length:
-                    # prefix-hit rows prefill only their suffix, a different
-                    # jit shape); one jit'd [B, bucket − prefix] prefill per
-                    # group — heterogeneous adapters batch fine, the
-                    # SGMV/einsum delta is per-row
-                    chunk = ecfg.prefill_chunk
-                    groups: Dict[Tuple[int, bool, int], List[Slot]] = {}
-                    for slot in prefilling:
-                        self._slot_prompt(slot)
-                        # chunked: progress starts at the prefix-cache hit
-                        # length (those positions are already served from
-                        # shared pages) and groups key off it — same-progress
-                        # rows share one jit shape, like same-prefix rows do
-                        if slot.prefill_pos < slot.prefix_len:
-                            slot.prefill_pos = slot.prefix_len
-                        start = (slot.prefill_pos if self.chunked
-                                 else slot.prefix_len)
-                        groups.setdefault(
-                            (slot.bucket, slot.merged, start),
-                            []).append(slot)
-                    work: List[Tuple[int, bool, int, List[Slot]]] = []
-                    for (b, merged, pfx), group in groups.items():
-                        if ecfg.prefill_batching:
-                            work.append((b, merged, pfx, group))
-                        else:  # pre-batching baseline: one B=1 call per slot
-                            work.extend((b, merged, pfx, [s]) for s in group)
-                    for b, merged, start, group in work:
-                        span = b - start
-                        # whole-span groups take the existing un-chunked
-                        # paths (prefill_chunk=None stays bit-identical; a
-                        # terminal paged chunk reuses the prefix-suffix
-                        # machinery wholesale). Dense mid-prompt progress
-                        # (start > 0) always routes through _prefill_chunk —
-                        # _prefill_group's suffix branch is paged-only.
-                        if not self.chunked or (chunk >= span
-                                                and (start == 0 or self.paged)):
-                            now += self._prefill_group(b, merged, start,
-                                                       group, now)
-                        else:
-                            now += self._prefill_chunk(
-                                b, merged, start, min(chunk, span), group, now)
-                    progressed = True
-
-                # ---- batched decode (Batch LoRA Inference) ----------------
-                gen = self.slots.in_state(SlotState.GENERATE)
-                if gen and self.paged:
-                    # allocate this step's page per sequence up front; a dry
-                    # arena preempts the youngest admission (LIFO restart —
-                    # greedy decode recomputes the identical stream later)
-                    gen = self._secure_decode_blocks(gen, now)
-                    progressed = True  # preemption alone is progress
-                if gen:
-                    rids = ([s.request.request_id for s in gen]
-                            if tr is not None else None)
-                    tokens = np.zeros((ecfg.n_slots,), np.int32)
-                    pos = np.zeros((ecfg.n_slots,), np.int32)
-                    sids = np.zeros((ecfg.n_slots,), np.int32)
-                    for slot in gen:
-                        tokens[slot.index] = slot.last_token
-                        pos[slot.index] = slot.pos
-                        sids[slot.index] = slot.adapter_slot
-                    merged_step = (ecfg.policy == "llamacpp"
-                                   or (ecfg.policy == "dlora"
-                                       and dlora_mode == "merged"))
-                    if self.paged:
-                        tables, lengths, plens, bwlens = \
-                            self._decode_tables(gen)
-                        if merged_step:
-                            (next_toks, self.cache), dt = self._timed(
-                                ("decode_merged",), self._decode_merged_paged,
-                                self.params, jnp.asarray(tokens), self.cache,
-                                tables, lengths, plens, bwlens,
-                                jnp.asarray(pos), now=now, requests=rids)
-                        else:
-                            (next_toks, self.cache), dt = self._timed(
-                                ("decode",), self._decode_paged, self.params,
-                                self.lora_pool, jnp.asarray(tokens),
-                                self.cache, tables, lengths, plens, bwlens,
-                                jnp.asarray(pos), jnp.asarray(sids),
-                                now=now, requests=rids)
-                    elif merged_step:
-                        (next_toks, self.cache), dt = self._timed(
-                            ("decode_merged",), self._decode_merged,
-                            self.params, jnp.asarray(tokens), self.cache,
-                            jnp.asarray(pos), now=now, requests=rids)
-                    else:
-                        (next_toks, self.cache), dt = self._timed(
-                            ("decode",), self._decode, self.params,
-                            self.lora_pool, jnp.asarray(tokens), self.cache,
-                            jnp.asarray(pos), jnp.asarray(sids),
-                            now=now, requests=rids)
-                    now += dt
-                    self.decode_steps += 1
-                    next_np = np.asarray(next_toks)  # el: allow[host-sync]
-                    for slot in gen:
-                        req = slot.request
-                        slot.last_token = int(next_np[slot.index])
-                        slot.pos += 1
-                        req.generated += 1
-                        req.tokens.append(slot.last_token)
-                        if req.generated >= req.output_len \
-                                or slot.pos >= ecfg.max_ctx - 1:
-                            req.finish_time = now
-                            if ecfg.policy != "llamacpp" \
-                                    and not slot.merged:
-                                self.manager.unpin(req.selected_adapter)
-                            if tr is not None:
-                                tr.transition(now, slot.index, "generate",
-                                              "idle", req)
-                            if self.paged:
-                                self.kvpool.release(req.request_id)
-                            completed.append(slot.release())
-                    progressed = True
-
-                # ---- per-iteration step time (compute charged this tick) --
-                step_busy = self.busy_time - busy0
-                if step_busy > 0.0:
-                    self._note_step(step_busy)
-
-                # ---- once-per-step metrics sampling (tracing only) --------
-                if tr is not None:
-                    if self.paged:
-                        tr.metrics.gauge("arena_blocks_used").set(
-                            self.kvpool.used_blocks)
-                    tr.sample(
-                        now,
-                        queue_depth=len(self._ready),
-                        active_slots=sum(s.state != SlotState.IDLE
-                                         for s in self.slots.slots),
-                        decode_batch=len(gen),
-                        resident_adapters=self.manager.n_resident,
-                        loading_adapters=len(self.manager.loading))
-
-                # ---- idle / load-blocked: jump to the earliest event ------
-                if not progressed:
-                    loading = self.slots.in_state(SlotState.LOADING)
-                    if loading:
-                        wake = min(s.ready_time for s in loading)
-                        if not self._ready and self._qi < len(queue):
-                            arr = max(now, queue[self._qi].arrival_time)
-                            if now < arr < wake:
-                                now = arr  # an arrival may unblock admission
+                                sb = np.asarray(sb)  # el: allow[host-sync] -- host argmax
+                                for i, slot in enumerate(group):
+                                    slot.sel_scores = sb[i]
+                        for slot in self.slots.in_state(SlotState.SELECTING):
+                            req = slot.request
+                            if ecfg.policy == "dlora":
+                                req.selected_adapter = req.true_adapter
+                                slot.merged = dlora_mode == "merged"
+                                if not slot.merged:
+                                    try:
+                                        res = self._acquire(
+                                            req.selected_adapter, now)
+                                    except PoolExhaustedError:
+                                        if tr is not None:
+                                            tr.sched(now, "defer_pool", request=req)
+                                        continue  # pool fully pinned: defer (see below)
+                                    now = self._finish_acquire(slot, res, now)
+                                else:
+                                    slot.adapter_slot = 0
+                                    slot.state = SlotState.PREFILL
+                                    if tr is not None:
+                                        tr.transition(now, slot.index, "selecting",
+                                                      "prefill", req)
+                                progressed = True
                                 continue
-                        # every runnable slot is load-blocked: the clock
-                        # stalls on the transfer channel — the serialization
-                        # async swap-in exists to minimize
-                        self.load_stall_seconds += max(0.0, wake - now)
-                        now = max(now, wake)
-                    elif self._ready:
-                        continue  # unreachable in practice: ready work
-                        # re-admits (or an active slot progresses) next tick
-                    elif self._qi < len(queue):
-                        now = max(now, queue[self._qi].arrival_time)
-                    else:
-                        break
+                            slot.merged = False
+                            if ecfg.policy == "llamacpp":
+                                # baseline executes MERGED: the active adapter was
+                                # folded into W at admission (cost charged there), so
+                                # steps must skip LoRA math entirely — running the
+                                # batched path with adapter_slot=0 would silently
+                                # apply whatever adapter sits in pool slot 0
+                                req.selected_adapter = req.true_adapter
+                                slot.merged = True
+                            elif (ecfg.policy == "edgelora_no_aas"
+                                  or req.adapter_id is not None):
+                                # explicit adapter: bypass adaptive
+                                # selection (Alg 1 l.1)
+                                req.selected_adapter = (req.adapter_id
+                                                        if req.adapter_id is not None
+                                                        else req.true_adapter)
+                            else:
+                                # scores are computed (and, for a learned router,
+                                # charged) once per request and cached on the slot: a
+                                # pool-exhausted deferral below must not re-roll the
+                                # oracle RNG or re-charge a router forward on retry
+                                scores = slot.sel_scores
+                                if scores is None:
+                                    if getattr(self.router, "costs_forward", False):
+                                        # solo fallback (router_batching off): one
+                                        # router forward ≈ one prompt pass (Table 6)
+                                        toks = self._slot_prompt(slot)[None, :]
+                                        rids = ([req.request_id]
+                                                if tr is not None else None)
+                                        sb, dt = self._timed(("router", slot.bucket, 1),
+                                                             self.router.scores_batch,
+                                                             toks, now=now,
+                                                             requests=rids)
+                                        now += dt
+                                        self.router_steps += 1
+                                        scores = np.asarray(sb)[0]  # el: allow[host-sync]
+                                    else:
+                                        scores = np.asarray(self.router.scores(req))
+                                    slot.sel_scores = scores
+                                # re-select from cached scores each attempt: the pool
+                                # contents change while deferred, so a cached top-k
+                                # adapter may become acquirable (Algorithm 1 intent)
+                                aid, _ = select_adapter(scores, self.manager,
+                                                        ecfg.top_k)
+                                req.selected_adapter = aid
+                            if ecfg.policy != "llamacpp":
+                                try:
+                                    res = self._acquire(
+                                        req.selected_adapter, now)
+                                except PoolExhaustedError:
+                                    # every pool block is pinned by an in-flight
+                                    # request (γ > R under adapter-diverse load):
+                                    # leave the slot SELECTING and retry after a
+                                    # completion unpins — pins are only held by
+                                    # LOADING/PREFILL/GENERATE slots, so the loop
+                                    # always progresses elsewhere
+                                    if tr is not None:
+                                        tr.sched(now, "defer_pool", request=req)
+                                    continue
+                                slot.sel_scores = None
+                                now = self._finish_acquire(slot, res, now)
+                            else:
+                                slot.sel_scores = None
+                                slot.adapter_slot = 0  # merged weights: adapter rides W
+                                slot.state = SlotState.PREFILL
+                                if tr is not None:
+                                    tr.transition(now, slot.index, "selecting",
+                                                  "prefill", req)
+                            if self.prefix_enabled and \
+                                    self._admission_exec_key(req, dlora_mode) is None:
+                                # AAS-routed: the adapter was unknown at admission —
+                                # match now and swap shared pages into the reserved
+                                # table (capacity accounting stays conservative)
+                                self._attach_prefix(slot)
+                            progressed = True
+
+                    # ---- async swap-in: transfers that have landed ------------
+                    if ecfg.async_swap:
+                        for slot in self.slots.in_state(SlotState.LOADING):
+                            if slot.ready_time <= now:
+                                slot.state = SlotState.PREFILL
+                                if tr is not None:
+                                    tr.transition(now, slot.index, "loading",
+                                                  "prefill", slot.request)
+                                progressed = True
+                        # queue-ahead prefetch: start transfers for upcoming
+                        # demand while the channel would otherwise sit idle
+                        # (behind any demand loads booked this tick)
+                        if ecfg.prefetch_depth > 0 and ecfg.policy != "llamacpp":
+                            with (tr.span("adapter.prefetch")
+                                  if tr is not None else NO_SPAN):
+                                self._run_prefetch(now, dlora_mode)
+
+                    # ---- prefill (gather→batch→scatter) ----------------------
+                    prefilling = self.slots.in_state(SlotState.PREFILL)
+                    if prefilling:
+                        prids = ([s.request.request_id for s in prefilling]
+                                 if tr is not None else None)
+                        with (tr.span("prefill", requests=prids)
+                              if tr is not None else NO_SPAN):
+                            # group same-bucket slots (split by merged-ness: merged
+                            # steps skip LoRA math entirely — and by prefix length:
+                            # prefix-hit rows prefill only their suffix, a different
+                            # jit shape); one jit'd [B, bucket − prefix] prefill per
+                            # group — heterogeneous adapters batch fine, the
+                            # SGMV/einsum delta is per-row
+                            chunk = ecfg.prefill_chunk
+                            groups: Dict[Tuple[int, bool, int], List[Slot]] = {}
+                            for slot in prefilling:
+                                self._slot_prompt(slot)
+                                # chunked: progress starts at the prefix-cache hit
+                                # length (those positions are already served from
+                                # shared pages) and groups key off it — same-progress
+                                # rows share one jit shape, like same-prefix rows do
+                                if slot.prefill_pos < slot.prefix_len:
+                                    slot.prefill_pos = slot.prefix_len
+                                start = (slot.prefill_pos if self.chunked
+                                         else slot.prefix_len)
+                                groups.setdefault(
+                                    (slot.bucket, slot.merged, start),
+                                    []).append(slot)
+                            work: List[Tuple[int, bool, int, List[Slot]]] = []
+                            for (b, merged, pfx), group in groups.items():
+                                if ecfg.prefill_batching:
+                                    work.append((b, merged, pfx, group))
+                                else:  # pre-batching baseline: one B=1 call per slot
+                                    work.extend((b, merged, pfx, [s]) for s in group)
+                            for b, merged, start, group in work:
+                                span = b - start
+                                # whole-span groups take the existing un-chunked
+                                # paths (prefill_chunk=None stays bit-identical; a
+                                # terminal paged chunk reuses the prefix-suffix
+                                # machinery wholesale). Dense mid-prompt progress
+                                # (start > 0) always routes through _prefill_chunk —
+                                # _prefill_group's suffix branch is paged-only.
+                                if not self.chunked or (chunk >= span
+                                                        and (start == 0 or self.paged)):
+                                    now += self._prefill_group(b, merged, start,
+                                                               group, now)
+                                else:
+                                    now += self._prefill_chunk(
+                                        b, merged, start, min(chunk, span), group, now)
+                            progressed = True
+
+                    # ---- batched decode (Batch LoRA Inference) ----------------
+                    gen = self.slots.in_state(SlotState.GENERATE)
+                    if gen and self.paged:
+                        # allocate this step's page per sequence up front; a dry
+                        # arena preempts the youngest admission (LIFO restart —
+                        # greedy decode recomputes the identical stream later)
+                        gen = self._secure_decode_blocks(gen, now)
+                        progressed = True  # preemption alone is progress
+                    if gen:
+                        rids = ([s.request.request_id for s in gen]
+                                if tr is not None else None)
+                        with (tr.span("decode", requests=rids)
+                              if tr is not None else NO_SPAN):
+                            tokens = np.zeros((ecfg.n_slots,), np.int32)
+                            pos = np.zeros((ecfg.n_slots,), np.int32)
+                            sids = np.zeros((ecfg.n_slots,), np.int32)
+                            for slot in gen:
+                                tokens[slot.index] = slot.last_token
+                                pos[slot.index] = slot.pos
+                                sids[slot.index] = slot.adapter_slot
+                            merged_step = (ecfg.policy == "llamacpp"
+                                           or (ecfg.policy == "dlora"
+                                               and dlora_mode == "merged"))
+                            if self.paged:
+                                tables, lengths, plens, bwlens = \
+                                    self._decode_tables(gen)
+                                if merged_step:
+                                    (next_toks, self.cache), dt = self._timed(
+                                        ("decode_merged",), self._decode_merged_paged,
+                                        self.params, jnp.asarray(tokens), self.cache,
+                                        tables, lengths, plens, bwlens,
+                                        jnp.asarray(pos), now=now, requests=rids)
+                                else:
+                                    (next_toks, self.cache), dt = self._timed(
+                                        ("decode",), self._decode_paged, self.params,
+                                        self.lora_pool, jnp.asarray(tokens),
+                                        self.cache, tables, lengths, plens, bwlens,
+                                        jnp.asarray(pos), jnp.asarray(sids),
+                                        now=now, requests=rids)
+                            elif merged_step:
+                                (next_toks, self.cache), dt = self._timed(
+                                    ("decode_merged",), self._decode_merged,
+                                    self.params, jnp.asarray(tokens), self.cache,
+                                    jnp.asarray(pos), now=now, requests=rids)
+                            else:
+                                (next_toks, self.cache), dt = self._timed(
+                                    ("decode",), self._decode, self.params,
+                                    self.lora_pool, jnp.asarray(tokens), self.cache,
+                                    jnp.asarray(pos), jnp.asarray(sids),
+                                    now=now, requests=rids)
+                            now += dt
+                            self.decode_steps += 1
+                            next_np = np.asarray(next_toks)  # el: allow[host-sync]
+                            for slot in gen:
+                                req = slot.request
+                                slot.last_token = int(next_np[slot.index])
+                                slot.pos += 1
+                                req.generated += 1
+                                req.tokens.append(slot.last_token)
+                                if req.generated >= req.output_len \
+                                        or slot.pos >= ecfg.max_ctx - 1:
+                                    req.finish_time = now
+                                    if ecfg.policy != "llamacpp" \
+                                            and not slot.merged:
+                                        self.manager.unpin(req.selected_adapter)
+                                    if tr is not None:
+                                        tr.transition(now, slot.index, "generate",
+                                                      "idle", req)
+                                    if self.paged:
+                                        self.kvpool.release(req.request_id)
+                                    completed.append(slot.release())
+                            progressed = True
+
+                    # ---- per-iteration step time (compute charged this tick) --
+                    step_busy = self.busy_time - busy0
+                    if step_busy > 0.0:
+                        self._note_step(step_busy)
+
+                    # ---- once-per-step metrics sampling (tracing only) --------
+                    if tr is not None:
+                        if self.paged:
+                            tr.metrics.gauge("arena_blocks_used").set(
+                                self.kvpool.used_blocks)
+                        tr.sample(
+                            now,
+                            queue_depth=len(self._ready),
+                            active_slots=sum(s.state != SlotState.IDLE
+                                             for s in self.slots.slots),
+                            decode_batch=len(gen),
+                            resident_adapters=self.manager.n_resident,
+                            loading_adapters=len(self.manager.loading))
+
+                    # ---- idle / load-blocked: jump to the earliest event ------
+                    if not progressed:
+                        loading = self.slots.in_state(SlotState.LOADING)
+                        if loading:
+                            wake = min(s.ready_time for s in loading)
+                            if not self._ready and self._qi < len(queue):
+                                arr = max(now, queue[self._qi].arrival_time)
+                                if now < arr < wake:
+                                    now = arr  # an arrival may unblock admission
+                                    continue
+                            # every runnable slot is load-blocked: the clock
+                            # stalls on the transfer channel — the serialization
+                            # async swap-in exists to minimize
+                            self.load_stall_seconds += max(0.0, wake - now)
+                            now = max(now, wake)
+                        elif self._ready:
+                            continue  # unreachable in practice: ready work
+                            # re-admits (or an active slot progresses) next tick
+                        elif self._qi < len(queue):
+                            now = max(now, queue[self._qi].arrival_time)
+                        else:
+                            break
 
             if tr is not None:
                 tr.finish(now)
@@ -1625,6 +1669,19 @@ class EdgeLoRAEngine:
     # adapter swap-in (reservation routing, queue-ahead prefetch)
     # ------------------------------------------------------------------
 
+    def _acquire(self, adapter_id: int, now: float) -> Reservation:
+        """A demand ``manager.acquire``; traced, an ``adapter.acquire``
+        span (a miss's ``adapter.load`` nests in it) and a
+        ``loads.demand`` count per miss."""
+        tr = self.tracer
+        if tr is None:
+            return self.manager.acquire(adapter_id, now=now)
+        with tr.span("adapter.acquire", adapter=adapter_id):
+            res = self.manager.acquire(adapter_id, now=now)
+        if res.loaded:
+            tr.metrics.counter("loads.demand").inc()
+        return res
+
     def _finish_acquire(self, slot: Slot, res: Any,
                         now: float) -> float:
         """Pin the reserved adapter and route the slot by swap mode:
@@ -1741,7 +1798,9 @@ class EdgeLoRAEngine:
         for aid in todo:
             if self.manager.channel_free_at > horizon:
                 break
-            self.manager.prefetch(aid, now=now, protect=protect)
+            res = self.manager.prefetch(aid, now=now, protect=protect)
+            if res is not None and self.tracer is not None:
+                self.tracer.metrics.counter("loads.prefetch").inc()
 
     # ------------------------------------------------------------------
     # paged-KV scheduling (block tables, preemption)

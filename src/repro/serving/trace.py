@@ -1,9 +1,15 @@
-"""Engine-wide tracing: typed events and spans on the virtual clock.
+"""Engine-wide tracing: typed events and spans on the virtual clock,
+and host spans on the wall clock.
 
 The serving engine's timeline is *virtual* — the clock advances by
 measured jit'd-step wall-times plus cost-model charges — so a trace of
-that clock is a complete, deterministic record of where every request's
-latency went. :class:`EngineTracer` collects that record:
+that clock is a deterministic record of the engine's scheduling
+decisions and of the latency it *charges* each request. It is not a
+record of where wall time went: the charges are an EWMA of step times
+plus modelled transfers (``adapter_bytes / disk_bandwidth``), and host
+work between steps (input preparation, adapter pool rewrites, the
+scheduler itself) is charged nothing. The **host spans** below measure
+that. :class:`EngineTracer` collects both records:
 
 * **slot state spans** — each slot's residency in SELECTING / LOADING /
   PREFILL / GENERATE, one span per state visit (IDLE is the gap between
@@ -21,38 +27,51 @@ latency went. :class:`EngineTracer` collects that record:
   timeout / preempt / requeue / merge instants;
 * **compile events** — every first-seen ``_timed`` key (a jit
   compilation), feeding the recompile watchdog
-  (:func:`jit_cache_report`).
+  (:func:`jit_cache_report`);
+* **host spans** (:meth:`EngineTracer.span`) — nested wall-clock
+  intervals of ``serve()``'s phases (``serve``, ``iter``, ``admit``,
+  ``select``, ``adapter.acquire`` / ``.prefetch`` / ``.load``,
+  ``prefill``, ``decode``, ``wait``), each also emitted as a
+  ``jax.profiler`` annotation named ``edgelora.<name>``, so a profiler
+  trace carries them on the device trace's own clock.
 
 From the slot spans the tracer derives a **per-request latency
-breakdown**: each completed request's end-to-end latency decomposed
-into ``queue_wait`` (arrival→admission, including re-queue waits after
-a preemption), ``select``, ``load_stall``, ``prefill``, ``decode``, and
-``preempted`` (in-slot time discarded by a KV preemption). The six
-segments provably sum to ``finish − arrival``: every instant of the
-request's life is spent either queued or resident in exactly one slot
-state — the tracer just integrates the transition times the engine
-already moves requests through.
+breakdown** of the virtual timeline: each completed request's
+end-to-end latency decomposed into ``queue_wait`` (arrival→admission,
+including re-queue waits after a preemption), ``select``,
+``load_stall``, ``prefill``, ``decode``, and ``preempted`` (in-slot time
+discarded by a KV preemption). The six segments provably sum to
+``finish − arrival``: every instant of the request's life is spent
+either queued or resident in exactly one slot state — the tracer just
+integrates the transition times the engine already moves requests
+through.
 
 The tracer is **opt-in and zero-cost when absent**: every engine call
 site guards on ``self.tracer is not None``, so ``tracer=None`` (the
 default) allocates nothing and the token streams / summary are
 bit-identical to an untraced engine (regression-tested). A traced run
-also never changes behavior — instrumentation is read-only — so
+also never changes behavior — instrumentation is read-only, and host
+spans read the wall clock without feeding it into ``now`` — so
 enabling it only adds the recording overhead.
 
 ``EngineTracer.export(path)`` writes a Chrome-trace/Perfetto JSON
 (``traceEvents`` with slots, channel, arena, scheduler, and compute as
-tracks, metrics series as counter tracks) plus an ``edgelora`` section
-carrying the raw events, metric series, per-request breakdowns, and
-the watchdog report — ``tools/trace_report.py`` analyzes that section,
-and ``benchmarks/schema.py``'s ``validate_trace_file`` schema-checks
-the whole artifact in CI.
+tracks, metrics series as counter tracks, and the host spans as a
+process of their own on the wall clock) plus an ``edgelora`` section
+carrying the raw events, host spans, metric series, per-request
+breakdowns, and the watchdog report — ``tools/trace_report.py``
+analyzes that section, and ``benchmarks/schema.py``'s
+``validate_trace_file`` schema-checks the whole artifact in CI.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+import time
+from typing import Any, ContextManager, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.serving.metrics_registry import MetricsRegistry
 
@@ -70,6 +89,51 @@ BREAKDOWN_SEGMENTS = ("queue_wait", "select", "load_stall", "prefill",
 _PREFILL_KINDS = ("prefill", "prefill_merged", "prefill_sfx",
                   "prefill_sfx_merged", "prefill_sfx_dense",
                   "prefill_sfx_dense_merged")
+
+
+# prefix of the jax.profiler annotation each host span emits
+SPAN_PREFIX = "edgelora."
+
+# the counters the engine's adapter load sites feed
+LOAD_COUNTERS = ("loads.demand", "loads.prefetch", "load.bytes")
+
+# what ``EngineTracer.span`` returns with spans off (and what engine
+# sites enter with no tracer): one shared, reusable no-op context
+NO_SPAN: ContextManager[None] = contextlib.nullcontext()
+
+
+def _wall_ns() -> int:
+    return time.perf_counter_ns()  # el: allow[clock] -- host spans only observe
+
+
+class _Span:
+    """One open host span: a record in ``tracer.spans`` (closed on exit)
+    and a ``jax.profiler`` annotation for the same interval."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_rec", "_note")
+
+    def __init__(self, tracer: "EngineTracer", name: str,
+                 args: Dict[str, Any]) -> None:
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+
+    def __enter__(self) -> None:
+        tr = self._tracer
+        stack = tr._open_spans
+        rec: List[Any] = [len(tr.spans), stack[-1] if stack else None,
+                          self._name, 0, None, self._args]
+        tr.spans.append(rec)
+        stack.append(rec[0])
+        self._rec = rec
+        self._note = TraceAnnotation(SPAN_PREFIX + self._name)
+        self._note.__enter__()
+        rec[3] = _wall_ns()
+
+    def __exit__(self, *exc: Any) -> None:
+        self._rec[4] = _wall_ns()
+        self._note.__exit__(None, None, None)
+        self._tracer._open_spans.pop()
 
 
 class JitRecompileError(RuntimeError):
@@ -121,13 +185,36 @@ class EngineTracer:
         self.events: List[Dict] = []
         self.now = 0.0
         self.metrics = MetricsRegistry()
+        for name in LOAD_COUNTERS:
+            self.metrics.counter(name)
         self.strict_watchdog = strict_watchdog
         self.meta: Dict = {}
         self.watchdog_report: Optional[Dict] = None
+        # host spans: [id, parent_id, name, t0_ns, t1_ns, args] in start
+        # order (t1_ns is None while open); record_spans=False turns
+        # span() into the shared no-op context
+        self.record_spans = True
+        self.spans: List[List[Any]] = []
+        self._open_spans: List[int] = []
         self._slot_state: Dict[int, Tuple[str, float, Optional[int]]] = {}
         self._acct: Dict[int, _RequestAcct] = {}
         self._began = False
         self._finished = False
+
+    # -- host spans (wall clock) -----------------------------------------
+
+    def span(self, name: str, **args: Any) -> ContextManager[None]:
+        """A context that records one host span named ``name``: its
+        wall-clock interval (``time.perf_counter_ns``), the enclosing
+        open span as its parent, and ``args`` (request ids, sizes; a
+        list passed here may be filled while the span is open). It also
+        holds a ``jax.profiler.TraceAnnotation("edgelora." + name)``
+        open for the same interval, so a profiler trace carries the span
+        on the device trace's clock. With ``record_spans`` off it
+        returns :data:`NO_SPAN`: no clock read, no annotation."""
+        if not self.record_spans:
+            return NO_SPAN
+        return _Span(self, name, args)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -316,7 +403,9 @@ class EngineTracer:
 
         Layout: one process, one thread per slot, plus channel / arena /
         scheduler / compute threads; metric series become counter
-        tracks. Times are virtual-clock microseconds."""
+        tracks. Times are virtual-clock microseconds. Host spans, if
+        any, form a second process (``pid`` 1) on the wall clock, in
+        microseconds since the first span opened."""
         n_slots = int(self.meta.get("n_slots", 0))
         tids = {f"slot{i}": i for i in range(n_slots)}
         tids.update({"compute": 1000, "channel": 1001, "arena": 1002,
@@ -346,7 +435,25 @@ class EngineTracer:
             for t, v in series:
                 out.append({"ph": "C", "pid": 0, "tid": 0, "name": name,
                             "ts": t * 1e6, "args": {"value": v}})
+        out.extend(self._host_span_events())
         out.sort(key=lambda e: (e.get("ts", -1.0), e["ph"] != "M"))
+        return out
+
+    def _host_span_events(self) -> List[Dict]:
+        closed = [r for r in self.spans if r[4] is not None]
+        if not closed:
+            return []
+        base = closed[0][3]
+        out: List[Dict] = [
+            {"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
+             "args": {"name": "edgelora-host (wall clock)"}},
+            {"ph": "M", "pid": 1, "tid": 0, "name": "thread_name",
+             "args": {"name": "serve"}}]
+        for sid, parent, name, t0, t1, args in closed:
+            out.append({"ph": "X", "pid": 1, "tid": 0, "name": name,
+                        "cat": "host", "ts": (t0 - base) * 1e-3,
+                        "dur": (t1 - t0) * 1e-3,
+                        "args": dict(args, id=sid, parent=parent)})
         return out
 
     def to_json(self) -> Dict:
@@ -361,6 +468,7 @@ class EngineTracer:
                 "meta": self.meta,
                 "duration": self.now,
                 "events": self.events,
+                "spans": self.spans,
                 "metrics": self.metrics.as_dict(),
                 "breakdowns": {str(rid): bd for rid, bd in
                                self.request_breakdowns().items()},

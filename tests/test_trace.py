@@ -134,15 +134,19 @@ def det_clock(monkeypatch):
 # every policy on both KV backends under the default (einsum) LoRA
 # backend, plus sgmv cells on the one policy that exercises the
 # unmerged batched-LoRA path (llamacpp is merged and never runs it;
-# edgelora_no_aas / unmerged dlora share edgelora's sgmv compute)
-_BIT_CASES = ([(p, kv, None) for p in POLICIES
-               for kv in ("dense", "paged")]
-              + [("edgelora", "dense", "sgmv"),
-                 ("edgelora", "paged", "sgmv")])
+# edgelora_no_aas / unmerged dlora share edgelora's sgmv compute);
+# host spans on, plus one case with them off
+_BIT_CASES = ([pytest.param(p, kv, None, True, id=f"{p}-{kv}-None")
+               for p in POLICIES for kv in ("dense", "paged")]
+              + [pytest.param("edgelora", kv, "sgmv", True,
+                              id=f"edgelora-{kv}-sgmv")
+                 for kv in ("dense", "paged")]
+              + [pytest.param("edgelora", "paged", None, False,
+                              id="edgelora-paged-None-spans_off")])
 
 
-@pytest.mark.parametrize("policy,kv,lora", _BIT_CASES)
-def test_tracing_bit_identical(policy, kv, lora, det_clock):
+@pytest.mark.parametrize("policy,kv,lora,spans", _BIT_CASES)
+def test_tracing_bit_identical(policy, kv, lora, spans, det_clock):
     cfg = _cfg()
     extra = {"lora_backend": lora} if lora else {}
     det_clock()
@@ -153,10 +157,12 @@ def test_tracing_bit_identical(policy, kv, lora, det_clock):
     assert eng_off.manager.on_event is None  # hooks never wired untraced
 
     tracer = EngineTracer()
+    tracer.record_spans = spans
     det_clock()
     t_on = _trace(cfg)
     eng_on = EdgeLoRAEngine(cfg, _ecfg(policy, kv, **extra), tracer=tracer)
     s_on = eng_on.serve(t_on)
+    assert bool(tracer.spans) == spans
 
     assert _tokens_by_id(t_off) == _tokens_by_id(t_on)
     assert _summary_fields(s_off) == _summary_fields(s_on)
@@ -344,12 +350,101 @@ def test_metrics_registry_unit():
     reg.sample(1.0)  # same t replaces, not appends
     reg.sample(2.0)
     assert reg.series["depth"] == [(1.0, 9.0), (2.0, 9.0)]
-    h = reg.histogram("step")
-    h.observe(0.01)
-    h.observe(0.7)
-    h.observe(1.5)
-    assert h.count == 3 and h.snapshot() == {
-        "le_0.125": 1, "le_1": 1, "le_2": 1}
+
+
+# ---------------------------------------------------------------------------
+# host spans (wall clock, mirrored as jax.profiler annotations)
+# ---------------------------------------------------------------------------
+
+
+def _burst_serve(tracer):
+    """A tiny serve with more tenants than pool slots, every request due
+    at once (so the prefetcher has a queue to warm): demand loads and
+    prefetches both happen. The tracer is attached after construction,
+    so the pool prefill at engine start records nothing."""
+    cfg = _cfg(n_adapters=12)
+    cfg = dataclasses.replace(
+        cfg, lora=dataclasses.replace(cfg.lora, max_resident=3))
+    trace = _trace(cfg, rate=6.0, duration=2.0)
+    for r in trace:
+        r.arrival_time = 0.0
+    eng = EdgeLoRAEngine(cfg, _ecfg("edgelora_no_aas"))
+    stats = eng.manager.stats
+    before = (stats.misses, stats.prefetch_issued)
+    eng.tracer = tracer
+    eng.serve(trace)
+    return trace, eng, before
+
+
+def test_host_spans_nest_close_and_count_loads():
+    tracer = EngineTracer()
+    trace, eng, (misses0, prefetch0) = _burst_serve(tracer)
+    spans = tracer.spans
+    by_id = {s[0]: s for s in spans}
+    assert [s[0] for s in spans] == list(range(len(spans)))
+    assert tracer._open_spans == []
+    for sid, parent, name, t0, t1, args in spans:
+        assert t1 is not None and t1 >= t0, name      # every span closed
+        if parent is None:
+            assert name == "serve"
+            continue
+        outer = by_id[parent]
+        assert outer[3] <= t0 <= t1 <= outer[4]
+        want = {"iter": {"serve"},
+                "admit": {"iter"}, "select": {"iter"},
+                "adapter.prefetch": {"iter"}, "prefill": {"iter"},
+                "decode": {"iter"},
+                "adapter.acquire": {"select"},
+                "adapter.load": {"adapter.acquire", "adapter.prefetch"},
+                "wait": {"prefill", "decode", "select"}}[name]
+        assert outer[2] in want, (name, outer[2])
+    names = [s[2] for s in spans]
+    assert names.count("serve") == 1
+    # each finished request was admitted in exactly one admit span
+    admitted = [rid for s in spans if s[2] == "admit"
+                for rid in s[5]["requests"]]
+    finished = [r.request_id for r in trace if r.finish_time is not None]
+    assert finished and sorted(admitted) == sorted(finished)
+    # the load counters agree with the manager's own accounting, and
+    # each load span sits under the call that caused it
+    stats = eng.manager.stats
+    demand = tracer.metrics.counter("loads.demand").value
+    prefetch = tracer.metrics.counter("loads.prefetch").value
+    assert demand == stats.misses - misses0 > 0
+    assert prefetch == stats.prefetch_issued - prefetch0 > 0
+    loads = [s for s in spans if s[2] == "adapter.load"]
+    assert len(loads) == demand + prefetch
+    assert sum(by_id[s[1]][2] == "adapter.acquire" for s in loads) == demand
+    assert tracer.metrics.counter("load.bytes").value == \
+        len(loads) * eng.adapter_bytes
+    assert all(s[5]["bytes"] == eng.adapter_bytes
+               and s[5]["pool_bytes"] > s[5]["bytes"] for s in loads)
+
+
+def test_host_spans_match_profiler_annotations(tmp_path):
+    """The spans land in a jax.profiler trace as ``edgelora.<name>``
+    host events, one for one, in the same order."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracer = EngineTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _burst_serve(tracer)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("edgelora."):
+                    events.append((e.start_ns, -e.duration_ns,
+                                   e.name[len("edgelora."):]))
+    assert [name for _, _, name in sorted(events)] == \
+        [s[2] for s in tracer.spans]
 
 
 # ---------------------------------------------------------------------------

@@ -57,6 +57,16 @@ def test_exported_trace_validates(traced):
     assert data["displayTimeUnit"] == "ms"
     phases = {ev["ph"] for ev in data["traceEvents"]}
     assert "M" in phases and "X" in phases and "C" in phases
+    # host spans: in the raw section, and as their own Perfetto process
+    # (pid 1, wall clock) apart from the virtual-clock tracks (pid 0)
+    spans = data["edgelora"]["spans"]
+    assert "serve" in [s[2] for s in spans]
+    host = [ev for ev in data["traceEvents"]
+            if ev["pid"] == 1 and ev["ph"] == "X"]
+    assert [ev["name"] for ev in host] == [s[2] for s in spans]
+    assert all(ev["cat"] == "host" for ev in host)
+    assert not any(ev.get("cat") == "host" for ev in data["traceEvents"]
+                   if ev["pid"] == 0)
 
 
 def test_missing_file_and_bad_json(tmp_path):
@@ -70,7 +80,8 @@ def test_missing_file_and_bad_json(tmp_path):
     "drop_trace_events", "empty_trace_events", "bad_phase", "nan_ts",
     "negative_dur", "drop_section", "wrong_version", "break_sum",
     "nan_segment", "drop_breakdowns", "nonfinite_duration",
-    "empty_raw_events",
+    "empty_raw_events", "drop_spans", "span_open", "span_bad_parent",
+    "span_escapes_parent",
 ])
 def test_corrupted_traces_fail(traced, corrupt):
     _, original = traced
@@ -107,6 +118,14 @@ def test_corrupted_traces_fail(traced, corrupt):
         data["edgelora"]["duration"] = float("inf")
     elif corrupt == "empty_raw_events":
         data["edgelora"]["events"] = []
+    elif corrupt == "drop_spans":
+        del data["edgelora"]["spans"]
+    elif corrupt == "span_open":
+        data["edgelora"]["spans"][-1][4] = None
+    elif corrupt == "span_bad_parent":
+        data["edgelora"]["spans"][1][1] = 5
+    elif corrupt == "span_escapes_parent":
+        data["edgelora"]["spans"][1][4] = data["edgelora"]["spans"][0][4] + 1
     assert validate_trace_json(data) != [], corrupt
 
 
