@@ -9,8 +9,9 @@ result, unless JAX's first device is a TPU. Then:
    program must hold the Pallas kernel, ``tpu_custom_call``) against
    ``kernels/ref.sgmv_ref`` with random nonzero A and B and mixed slot
    ids, at qwen2-0.5b's four LoRA projection widths and at a decode and a
-   prefill token count. The serve runs cannot catch a wrong kernel: every
-   engine adapter starts with B = 0, so its delta is exactly zero.
+   prefill token count, with bf16 pools and with the engine's f32 pool.
+   The serve runs cannot catch a wrong kernel: every engine adapter
+   starts with B = 0, so its delta is exactly zero.
 2. Two serves through the CLI's own build path (``repro.launch.serve``:
    ``--arch qwen2-0.5b --full --n-adapters 1024``), one with dense KV and
    one with paged KV. Every request must complete with its requested
@@ -64,28 +65,34 @@ def sgmv_parity(cfg, seed: int = 0) -> dict:
               (cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)]
     rng = np.random.default_rng(seed)
     errors = {}
-    for d_in, d_out in widths:
-        a = jnp.asarray(rng.normal(size=(n_slots, rank, d_in))
-                        / np.sqrt(d_in), jnp.bfloat16)
-        b = jnp.asarray(rng.normal(size=(n_slots, d_out, rank))
-                        / np.sqrt(rank), jnp.bfloat16)
-        for t in TOKEN_COUNTS:
-            x = jnp.asarray(rng.normal(size=(t, d_in)), jnp.bfloat16)
-            slots = jnp.asarray(rng.integers(0, n_slots, t), jnp.int32)
-            compiled = ops.sgmv.lower(x, a, b, slots, scale, n_slots=n_slots,
-                                      blk_t=None, interpret=False).compile()
-            check("tpu_custom_call" in compiled.as_text(),
-                  f"sgmv {d_in}->{d_out} T={t}: no Pallas kernel compiled")
-            y = np.asarray(compiled(x, a, b, slots, scale), np.float32)
-            with jax.default_matmul_precision("highest"):
-                y_ref = np.asarray(ref.sgmv_ref(x, a, b, slots, scale))
-            check(y.shape == y_ref.shape and bool(np.isfinite(y).all()),
-                  f"sgmv {d_in}->{d_out} T={t}: shape {y.shape} or "
-                  "non-finite values")
-            err = float(np.abs(y - y_ref).max() / np.abs(y_ref).max())
-            errors[f"{d_in}->{d_out}/T={t}"] = err
-            check(err <= SGMV_TOL, f"sgmv {d_in}->{d_out} T={t}: relative "
-                  f"error {err} > {SGMV_TOL}")
+    # bf16 pools, and the engine's f32 pool, whose tiles the kernels round
+    # to bf16 in VMEM: the reference takes the pool rounded the same way
+    for pool_dtype, tag in ((jnp.bfloat16, ""), (jnp.float32, "/f32pool")):
+        for d_in, d_out in widths:
+            a = jnp.asarray(rng.normal(size=(n_slots, rank, d_in))
+                            / np.sqrt(d_in), pool_dtype)
+            b = jnp.asarray(rng.normal(size=(n_slots, d_out, rank))
+                            / np.sqrt(rank), pool_dtype)
+            a16, b16 = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
+            for t in TOKEN_COUNTS:
+                what = f"sgmv {d_in}->{d_out} T={t}{tag}"
+                x = jnp.asarray(rng.normal(size=(t, d_in)), jnp.bfloat16)
+                slots = jnp.asarray(rng.integers(0, n_slots, t), jnp.int32)
+                compiled = ops.sgmv.lower(
+                    x, a, b, slots, scale, n_slots=n_slots, blk_t=None,
+                    interpret=False).compile()
+                check("tpu_custom_call" in compiled.as_text(),
+                      f"{what}: no Pallas kernel compiled")
+                y = np.asarray(compiled(x, a, b, slots, scale), np.float32)
+                with jax.default_matmul_precision("highest"):
+                    y_ref = np.asarray(ref.sgmv_ref(x, a16, b16, slots,
+                                                    scale))
+                check(y.shape == y_ref.shape and bool(np.isfinite(y).all()),
+                      f"{what}: shape {y.shape} or non-finite values")
+                err = float(np.abs(y - y_ref).max() / np.abs(y_ref).max())
+                errors[f"{d_in}->{d_out}/T={t}{tag}"] = err
+                check(err <= SGMV_TOL,
+                      f"{what}: relative error {err} > {SGMV_TOL}")
     return errors
 
 

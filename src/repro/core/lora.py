@@ -140,11 +140,11 @@ def _lora_delta_sgmv(x: jax.Array, a_stack: jax.Array, b_stack: jax.Array,
     else:
         token_slots = adapter_ids
         flat = x
-    # match the einsum backend's semantics (adapters computed at x.dtype);
-    # also keeps the kernel dot_generals single-dtype (f32 pool, bf16 x)
-    delta = ops.sgmv(flat, a_stack.astype(x.dtype),
-                     b_stack.astype(x.dtype), token_slots, scale,
-                     n_slots=a_stack.shape[0], blk_t=None,
+    # the pool goes in as stored (f32): the kernels round each tile they
+    # read to x.dtype, the einsum backend's semantics, so no step converts
+    # the slots its batch does not use
+    delta = ops.sgmv(flat, a_stack, b_stack, token_slots, scale,
+                     n_slots=a_stack.shape[0], blk_t=None, blk_d=None,
                      interpret=interpret)
     return delta.reshape(*x.shape[:-1], b_stack.shape[1])
 

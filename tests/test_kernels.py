@@ -9,7 +9,11 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
-from repro.kernels.sgmv import _fit
+from repro.kernels.sgmv import (DEFAULT_BLK_D, VMEM_BUDGET, _fit,
+                                expand_vmem, shrink_vmem)
+
+# qwen2-0.5b's LoRA projections (d_in, d_out): q/o, up/gate, down, k/v
+QWEN2_WIDTHS = [(896, 896), (896, 4864), (4864, 896), (896, 128)]
 
 
 # ---------------------------------------------------------------------------
@@ -56,15 +60,41 @@ def test_sgmv_single_adapter_matches_dense():
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("t", [16, 256], ids=["decode", "prefill"])
+@pytest.mark.parametrize("d_in,d_out", QWEN2_WIDTHS,
+                         ids=[f"{i}to{o}" for i, o in QWEN2_WIDTHS])
+def test_sgmv_f32_pool_vs_ref(d_in, d_out, t):
+    """The engine's dtype pair: bf16 x against the f32 pool, read in place
+    with each tile rounded to bf16 in the kernel. The reference takes the
+    pool rounded to bf16 first (what the einsum backend computes)."""
+    n_slots, r = 32, 16
+    rng = np.random.default_rng(t + d_in + d_out)
+    x = jnp.asarray(rng.normal(size=(t, d_in)), jnp.bfloat16)
+    a = jnp.asarray(rng.normal(size=(n_slots, r, d_in)) / np.sqrt(d_in),
+                    jnp.float32)
+    b = jnp.asarray(rng.normal(size=(n_slots, d_out, r)) / np.sqrt(r),
+                    jnp.float32)
+    slots = jnp.asarray(rng.integers(0, n_slots, t), jnp.int32)
+    y = ops.sgmv(x, a, b, slots, 2.0, n_slots=n_slots, blk_t=None,
+                 interpret=True)
+    assert y.dtype == jnp.bfloat16
+    y_ref = np.asarray(ref.sgmv_ref(x, a.astype(jnp.bfloat16),
+                                    b.astype(jnp.bfloat16), slots, 2.0))
+    np.testing.assert_allclose(np.asarray(y, np.float32), y_ref,
+                               rtol=1e-2, atol=1e-2 * np.abs(y_ref).max())
+
+
 @settings(max_examples=20, deadline=None)
-@given(t=st.integers(1, 40), n_slots=st.integers(1, 6),
+@given(t=st.integers(1, 40), n_slots=st.integers(1, 48),
        seed=st.integers(0, 2**31 - 1))
 def test_sgmv_grouping_property(t, n_slots, seed):
     """plan_grouping: permutation is a bijection, every block homogeneous,
-    padded positions unique and within bounds."""
+    padded positions unique and within bounds; blocks past the last used
+    one name the last used slot (no new adapter tile is fetched)."""
     rng = np.random.default_rng(seed)
     slots = jnp.asarray(rng.integers(0, n_slots, t), jnp.int32)
     plan = ops.plan_grouping(slots, n_slots, blk_t=8)
+    assert plan.n_padded == 8 * ops.n_blocks(t, n_slots, 8)
     perm = np.asarray(plan.perm)
     assert sorted(perm.tolist()) == list(range(t))
     pos = np.asarray(plan.padded_pos)
@@ -74,6 +104,18 @@ def test_sgmv_grouping_property(t, n_slots, seed):
     sorted_slots = np.asarray(slots)[perm]
     for token_idx, p in enumerate(pos):
         assert block_slots[p // 8] == sorted_slots[token_idx]
+    assert (block_slots[pos.max() // 8:] == sorted_slots[-1]).all()
+
+
+@pytest.mark.parametrize("t,n_slots,blk_t,n_padded", [
+    (16, 32, 8, 128),       # qwen2 decode: 16 blocks, not 34
+    (4096, 32, 128, 8192),  # largest prefill group: the bound is unchanged
+    (1, 32, 8, 8),
+])
+def test_sgmv_block_bound(t, n_slots, blk_t, n_padded):
+    """The static block count is ⌊T/blk_t⌋ + min(T, R), at most T."""
+    slots = jnp.arange(t, dtype=jnp.int32) % n_slots
+    assert ops.plan_grouping(slots, n_slots, blk_t).n_padded == n_padded
 
 
 @settings(max_examples=10, deadline=None)
@@ -104,6 +146,59 @@ def test_fit_lane_tile(n, requested):
     fits = [m for m in range(128, min(n, requested) + 1, 128) if n % m == 0]
     assert b == (max(fits) if fits else n)
     assert n % b == 0 and (b % 128 == 0 or b == n)
+
+
+@pytest.mark.parametrize("d_in,d_out", QWEN2_WIDTHS,
+                         ids=[f"{i}to{o}" for i, o in QWEN2_WIDTHS])
+def test_sgmv_grid_decode_whole_width(d_in, d_out):
+    """At qwen2 decode (T = 16, R = 32, bf16 x, f32 pool) each kernel
+    takes its whole lane axis in one step over 16 token blocks."""
+    g = ops.sgmv_grid(16, d_in, d_out, 16, 32, jnp.bfloat16, jnp.float32,
+                      jnp.float32, blk_t=None)
+    assert (g.blk_t, g.nb, g.nd_in, g.nd_out) == (8, 16, 1, 1)
+    assert (g.blk_d_in, g.blk_d_out) == (d_in, d_out)
+
+
+def test_sgmv_grid_tiles_wide_prefill():
+    """A width whose tiles do not fit the budget is still tiled: d_ff
+    18432 (starcoder2-7b) at blk_t 128, for both kernels."""
+    g = ops.sgmv_grid(4096, 18432, 18432, 16, 8, jnp.bfloat16, jnp.float32,
+                      jnp.float32, blk_t=128)
+    assert g.nd_in > 1 and g.nd_out > 1
+
+
+@pytest.mark.parametrize("x_dtype,pool_dtype", [
+    (jnp.bfloat16, jnp.float32), (jnp.bfloat16, jnp.bfloat16),
+    (jnp.float32, jnp.float32)])
+@pytest.mark.parametrize("blk_t", [8, 128])
+@pytest.mark.parametrize("width", [128, 640, 896, 1000, 4608, 4864, 14336,
+                                   18432, 49152])
+def test_budget_lane_tile(width, blk_t, x_dtype, pool_dtype):
+    """The budget's lane tile divides the width, is a multiple of 128 or
+    the whole width, fits VMEM_BUDGET, and is never narrower than the
+    fixed request's ``_fit(width, 512)`` (taken only when nothing wider
+    fits)."""
+    r, floor = 16, _fit(width, DEFAULT_BLK_D)
+    g = ops.sgmv_grid(4 * blk_t, width, width, r, 4, x_dtype, pool_dtype,
+                      pool_dtype, blk_t=blk_t)
+    for tile, vmem in [
+            (g.blk_d_in,
+             lambda b: shrink_vmem(blk_t, b, r, x_dtype, pool_dtype)),
+            (g.blk_d_out,
+             lambda b: expand_vmem(blk_t, b, r, pool_dtype, jnp.float32))]:
+        assert width % tile == 0 and (tile % 128 == 0 or tile == width)
+        assert tile >= floor
+        assert vmem(tile) <= VMEM_BUDGET or tile == floor
+        wider = [b for b in range(tile + 1, width + 1)
+                 if width % b == 0 and (b % 128 == 0 or b == width)]
+        assert all(vmem(b) > VMEM_BUDGET for b in wider)
+
+
+def test_explicit_blk_d_keeps_fit():
+    """An explicit blk_d keeps the fixed rule: _fit to each width."""
+    g = ops.sgmv_grid(16, 896, 4864, 16, 32, jnp.bfloat16, jnp.float32,
+                      jnp.float32, blk_t=None, blk_d=512)
+    assert (g.blk_d_in, g.blk_d_out) == (_fit(896, 512), _fit(4864, 512))
 
 
 # ---------------------------------------------------------------------------

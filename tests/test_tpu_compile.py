@@ -55,15 +55,20 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("t", [8, 256], ids=["decode", "prefill"])
+# bf16 pools, and the engine's pair (bf16 x, f32 pool read in place) at
+# its decode batch (16 slots) and its largest prefill group (16 x 256)
+@pytest.mark.parametrize("t,pool_dtype", [
+    (8, jnp.bfloat16), (256, jnp.bfloat16),
+    (16, jnp.float32), (4096, jnp.float32)],
+    ids=["decode", "prefill", "decode-f32pool", "prefill-f32pool"])
 @pytest.mark.parametrize("d_in,d_out", WIDTHS,
                          ids=[f"{i}to{o}" for i, o in WIDTHS])
-def test_sgmv_compiles_for_v5e(one_chip, d_in, d_out, t):
+def test_sgmv_compiles_for_v5e(one_chip, d_in, d_out, t, pool_dtype):
     n_slots, rank = CFG.lora.max_resident, CFG.lora.rank
     compiled = ops.sgmv.lower(
         _sds((t, d_in), jnp.bfloat16, one_chip),
-        _sds((n_slots, rank, d_in), jnp.bfloat16, one_chip),
-        _sds((n_slots, d_out, rank), jnp.bfloat16, one_chip),
+        _sds((n_slots, rank, d_in), pool_dtype, one_chip),
+        _sds((n_slots, d_out, rank), pool_dtype, one_chip),
         _sds((t,), jnp.int32, one_chip), CFG.lora.scale,
         n_slots=n_slots, blk_t=None, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
