@@ -6,9 +6,10 @@ a chip, and report what the compiler says.
 
 Builds the engine's own jitted prefill and decode steps (compiled SGMV,
 dense KV) from shapes alone, and the SGMV data path (``kernels/ops.sgmv``)
-at each LoRA projection's widths for a decode-sized and a prefill-sized
-token count. For each program it prints one JSON line: the bytes of
-``memory_analysis()`` and whether the compiled text holds the Pallas kernel
+at each LoRA projection's widths (the configuration's model module's
+``lora_modules``) for a decode-sized and a prefill-sized token count. For
+each program it prints one JSON line: the bytes of ``memory_analysis()``
+and whether the compiled text holds the Pallas kernel
 (``tpu_custom_call``). Nothing runs and nothing is timed.
 """
 from __future__ import annotations
@@ -47,7 +48,7 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench.harness.engine import model_config
-    from bench.models.dense_decoder import lora_shapes
+    from bench.models import module_of
     from repro.kernels import ops
     from repro.models import build_model
     from repro.serving.engine import EdgeLoRAEngine, EngineConfig
@@ -97,7 +98,7 @@ def main(argv=None) -> int:
         params, pool, i32(n), cache, i32(n), i32(n)))
     r, slots = mcfg.lora.rank, mcfg.lora.max_resident
     bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=chip)  # noqa: E731
-    for name, (d_in, d_out) in lora_shapes(cfg).items():
+    for name, (d_in, d_out) in module_of(cfg).lora_modules(cfg).items():
         for t in (n, g * args.bucket):
             report(f"sgmv {name} {d_in}->{d_out} T={t}", ops.sgmv.lower(
                 bf16(t, d_in), bf16(slots, r, d_in), bf16(slots, d_out, r),

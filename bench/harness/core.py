@@ -21,6 +21,7 @@ import numpy as np
 from bench.harness import correct, devtrace, stats, traffic
 from bench.harness.engine import (WallObserver, check_layout, engine_config,
                                   make_engine, model_config)
+from bench.models import module_of
 
 SALT_ENGINE = 0xE9
 WARMUP_ID = 10 ** 9
@@ -127,7 +128,7 @@ def build(c: Dict, seed: int, require_tpu: bool = True,
     """Weights from the seed, the engine, and the warm-up (or, with
     ``steps_from``, the compiled steps of an earlier set-up: ``steps_of``)."""
     cfg, mix = c["cfg"], c["mix"]
-    model = importlib.import_module(f"bench.models.{cfg['model']}")
+    model = module_of(cfg)
     eseed = int(np.random.SeedSequence([seed, SALT_ENGINE])
                 .generate_state(1)[0]) & 0x3FFFFFFF
     mcfg = model_config(cfg, mix.get("n_adapters"))

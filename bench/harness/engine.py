@@ -9,31 +9,65 @@ request at every step and count compiles, and do nothing else.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
+import types
+import typing
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-from repro.configs.base import AttentionConfig, LoRAConfig, ModelConfig
+from repro.configs.base import ModelConfig
 from repro.serving.engine import EdgeLoRAEngine, EngineConfig
 from repro.serving.trace import EngineTracer
 
-_CONFIG_KEYS = ("name", "family", "citation", "n_layers", "d_model",
-                "n_heads", "n_kv_heads", "d_ff", "vocab_size", "head_dim",
-                "norm_eps", "tie_embeddings", "act", "glu", "dtype")
+# keys of a configuration file that the benchmark reads itself; every other
+# key names a field of the program's ModelConfig
+HARNESS_KEYS = frozenset({"source", "reduced", "model", "deployment",
+                          "departures", "published", "assumed", "correct"})
+
+
+def _typed(hint: Any, value: Any, key: str) -> Any:
+    """A value as JSON gives it, in the type of a field annotated ``hint``:
+    an object becomes the field's dataclass, a list a tuple."""
+    if typing.get_origin(hint) in (typing.Union, types.UnionType):
+        args = [a for a in typing.get_args(hint) if a is not type(None)]
+        if value is None or len(args) != 1:
+            return value
+        hint = args[0]
+    if dataclasses.is_dataclass(hint) and isinstance(value, dict):
+        return _dataclass(hint, value, key + ".")
+    if (typing.get_origin(hint) or hint) is tuple and isinstance(value, list):
+        return tuple(value)
+    return value
+
+
+def _dataclass(cls: type, values: Dict, prefix: str = "") -> Any:
+    """``cls`` from a configuration file's keys; a key that names none of
+    its fields stops the run."""
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls) if f.init}
+    for key in values:
+        if key not in names:
+            raise ValueError(f"configuration key {prefix + key!r} is neither "
+                             f"one of the benchmark's own "
+                             f"({', '.join(sorted(HARNESS_KEYS))}) nor a "
+                             f"field of the program's {cls.__name__}")
+    return cls(**{k: _typed(hints[k], v, prefix + k)
+                  for k, v in values.items()})
 
 
 def model_config(cfg: Dict, n_adapters: Optional[int] = None) -> ModelConfig:
-    """The program's ModelConfig for a configuration file; ``n_adapters``
-    (from the traffic mix) overrides how many adapters are registered."""
-    lora = dict(cfg["lora"])
-    lora["target_modules"] = tuple(lora["target_modules"])
+    """The program's ModelConfig for a configuration file: every key but
+    the benchmark's own (``HARNESS_KEYS``) sets the field of that name, as
+    ``dataclasses.fields`` lists them. ``n_adapters`` (from the traffic mix)
+    overrides how many adapters are registered."""
+    mcfg = _dataclass(ModelConfig, {k: v for k, v in cfg.items()
+                                    if k not in HARNESS_KEYS})
     if n_adapters is not None:
-        lora["n_adapters"] = int(n_adapters)
-    attn = dict(cfg["attn"])
-    attn["layer_pattern"] = tuple(attn["layer_pattern"])
-    return ModelConfig(**{k: cfg[k] for k in _CONFIG_KEYS},
-                       attn=AttentionConfig(**attn), lora=LoRAConfig(**lora))
+        mcfg = dataclasses.replace(mcfg, lora=dataclasses.replace(
+            mcfg.lora, n_adapters=int(n_adapters)))
+    return mcfg
 
 
 def engine_config(mix: Dict, seed: int) -> EngineConfig:

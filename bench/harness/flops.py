@@ -5,42 +5,20 @@ Counts are of real work: real prompt tokens in a prefill group (not the
 padded bucket or replica rows), rows that hold a request in a decode step,
 and only the LoRA adapters that those rows use. A multiply-add is two
 operations.
+
+What depends on the block (the LoRA targets' widths, the weights of a
+layer, one token's operations through every layer) comes from the
+configuration's model module, ``bench/models/<model>.py``
+(``lora_modules``, ``layer_weight_params``, ``token_flops``); what is
+here holds for any block.
 """
 from __future__ import annotations
 
 from typing import Dict, Tuple
 
+from bench.models import module_of
+
 BF16 = 2
-
-
-def _hd(cfg: Dict) -> int:
-    return cfg.get("head_dim") or cfg["d_model"] // cfg["n_heads"]
-
-
-def lora_modules(cfg: Dict) -> Dict[str, Tuple[int, int]]:
-    """(d_in, d_out) of each LoRA target of one layer."""
-    d, f, hd = cfg["d_model"], cfg["d_ff"], _hd(cfg)
-    qs, kvs = cfg["n_heads"] * hd, cfg["n_kv_heads"] * hd
-    full = {"q": (d, qs), "k": (d, kvs), "v": (d, kvs), "o": (qs, d),
-            "up": (d, f), "gate": (d, f), "down": (f, d)}
-    return {m: full[m] for m in cfg["lora"]["target_modules"]
-            if m != "gate" or cfg["glu"]}
-
-
-def layer_weight_params(cfg: Dict) -> int:
-    """Weights of one layer's matrix multiplications."""
-    d, f, hd = cfg["d_model"], cfg["d_ff"], _hd(cfg)
-    qs, kvs = cfg["n_heads"] * hd, cfg["n_kv_heads"] * hd
-    return 2 * d * qs + 2 * d * kvs + (3 if cfg["glu"] else 2) * d * f
-
-
-def token_flops(cfg: Dict, context: int) -> int:
-    """One token through every layer, attending over ``context`` keys
-    (itself included): weights, LoRA and attention (scores and values)."""
-    r = cfg["lora"]["rank"]
-    lora = sum(2 * r * (i + o) for i, o in lora_modules(cfg).values())
-    attn = 4 * context * cfg["n_heads"] * _hd(cfg)
-    return cfg["n_layers"] * (2 * layer_weight_params(cfg) + lora + attn)
 
 
 def head_flops(cfg: Dict) -> int:
@@ -51,13 +29,14 @@ def head_flops(cfg: Dict) -> int:
 def prefill_flops(cfg: Dict, prompt_len: int) -> int:
     """A prompt's prefill: every prompt token under causal attention, and
     the logits of its last position only (the step reads no others)."""
+    token_flops = module_of(cfg).token_flops
     return sum(token_flops(cfg, i + 1) for i in range(prompt_len)) \
         + head_flops(cfg)
 
 
 def decode_flops(cfg: Dict, position: int) -> int:
     """One decode row feeding the token at ``position``."""
-    return token_flops(cfg, position + 1) + head_flops(cfg)
+    return module_of(cfg).token_flops(cfg, position + 1) + head_flops(cfg)
 
 
 def sgmv_need(tokens: int, n_adapters: int, d_in: int, d_out: int,
@@ -78,7 +57,7 @@ def sgmv_step_bound(cfg: Dict, tokens: int, n_adapters: int,
     operations over the peak rate and its bytes over the bandwidth)."""
     r = cfg["lora"]["rank"]
     t = 0.0
-    for d_in, d_out in lora_modules(cfg).values():
+    for d_in, d_out in module_of(cfg).lora_modules(cfg).values():
         fl, nb = sgmv_need(tokens, n_adapters, d_in, d_out, r)
         t += max(fl / peak_flops, nb / peak_bw)
     return cfg["n_layers"] * t

@@ -7,7 +7,14 @@ then a SwiGLU or a tanh-GELU MLP), a final RMSNorm and a tied or untied
 output head. LoRA pairs (A [r, d_in], B [d_out, r], delta
 ``alpha / r * x A^T B^T``) sit on the projections the file lists.
 
-This file imports nothing of the program under test. It makes:
+This file imports nothing of the program under test. ``check`` refuses,
+naming the key, a configuration that sets an option it does not compute
+(more than one kind of layer, query/key norms, soft-caps, chunked or
+position-free attention, post-norms, scaled embeddings, experts, state-space
+or encoder layers), or any key it does not know; ``make_params`` and
+``forward_logits`` call it. It holds the block's counts that
+``bench/harness/flops.py`` builds on (``lora_modules``,
+``layer_weight_params``, ``token_flops``), and makes:
 
 * ``make_params``: every base weight, in bf16, on the device, in one jitted
   call from the seed. The tree has the layout the serving engine takes as
@@ -24,7 +31,7 @@ This file imports nothing of the program under test. It makes:
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,19 +71,95 @@ def dims(cfg: Dict) -> Dict[str, int]:
             "L": cfg["n_layers"], "r": cfg["lora"]["rank"]}
 
 
-def lora_shapes(cfg: Dict) -> Dict[str, tuple]:
-    """(d_in, d_out) of each LoRA target, in the config's order."""
+# options of a configuration file that this reference does not compute,
+# each with the test that the file sets it: a block that needs one is
+# another model module's
+_NOT_COMPUTED = {
+    "attn.layer_pattern": lambda c: len(set(c["attn"]["layer_pattern"])) != 1
+    or c["attn"]["layer_pattern"][0] not in ("global", "local"),
+    "attn.qk_norm": lambda c: c["attn"].get("qk_norm", False),
+    "attn.attn_logit_softcap":
+        lambda c: c["attn"].get("attn_logit_softcap") is not None,
+    "attn.chunked_local": lambda c: c["attn"].get("chunked_local", False),
+    "attn.rope": lambda c: not c["attn"].get("rope", True),
+    "act": lambda c: c["act"] != ("silu" if c["glu"] else "gelu"),
+    "post_norm": lambda c: c.get("post_norm", False),
+    "final_logit_softcap": lambda c: c.get("final_logit_softcap") is not None,
+    "scale_embeddings": lambda c: c.get("scale_embeddings", False),
+    "moe": lambda c: c.get("moe") is not None,
+    "ssm": lambda c: c.get("ssm") is not None,
+    "encoder": lambda c: c.get("encoder") is not None,
+    "shared_attn_every": lambda c: c.get("shared_attn_every", 0),
+}
+
+
+# every other key of a configuration file that this module knows, by group:
+# what the block reads, the benchmark's own keys, and the program's backend
+# choices, which change no logit the reference computes. A key outside these
+# and ``_NOT_COMPUTED`` (a field the program adds later) is refused too.
+_KNOWN = {
+    "": {"name", "family", "citation", "n_layers", "d_model", "n_heads",
+         "n_kv_heads", "d_ff", "vocab_size", "head_dim", "norm_eps",
+         "tie_embeddings", "glu", "dtype", "attn", "lora", "lora_backend",
+         "kv_backend", "source", "reduced", "model", "deployment",
+         "departures", "published", "assumed", "correct"},
+    "attn.": {"sliding_window", "qkv_bias", "rope_theta", "kv_cache_quant"},
+    "lora.": {"rank", "alpha", "dropout", "target_modules", "max_resident",
+              "n_adapters"},
+}
+
+
+def _refuse(key: str, what: str) -> None:
+    raise ValueError(f"bench/models/dense_decoder.py {what} the "
+                     f"configuration's {key!r}; a block with it needs a "
+                     f"model module of its own")
+
+
+def check(cfg: Dict) -> None:
+    """Refuse a configuration that sets an option this reference does not
+    compute, or a key it does not know, naming the key."""
+    for prefix, known in _KNOWN.items():
+        group = cfg[prefix[:-1]] if prefix else cfg
+        for key in group:
+            if key not in known and prefix + key not in _NOT_COMPUTED:
+                _refuse(prefix + key, "does not know")
+    for key, is_set in _NOT_COMPUTED.items():
+        if is_set(cfg):
+            _refuse(key, "does not compute")
+
+
+# ---------------------------------------------------------------------------
+# counts of the block (bench/harness/flops.py builds on them)
+# ---------------------------------------------------------------------------
+
+
+def lora_modules(cfg: Dict) -> Dict[str, Tuple[int, int]]:
+    """(d_in, d_out) of each LoRA target of one layer, in the config's
+    order (``gate`` only in a gated MLP)."""
     m = dims(cfg)
     full = {"q": (m["d"], m["qs"]), "k": (m["d"], m["kvs"]),
             "v": (m["d"], m["kvs"]), "o": (m["qs"], m["d"]),
             "up": (m["d"], m["f"]), "gate": (m["d"], m["f"]),
             "down": (m["f"], m["d"])}
-    out = {}
-    for name in cfg["lora"]["target_modules"]:
-        if name == "gate" and not cfg["glu"]:
-            continue
-        out[name] = full[name]
-    return out
+    return {name: full[name] for name in cfg["lora"]["target_modules"]
+            if name != "gate" or cfg["glu"]}
+
+
+def layer_weight_params(cfg: Dict) -> int:
+    """Weights of one layer's matrix multiplications."""
+    m = dims(cfg)
+    return 2 * m["d"] * m["qs"] + 2 * m["d"] * m["kvs"] \
+        + (3 if cfg["glu"] else 2) * m["d"] * m["f"]
+
+
+def token_flops(cfg: Dict, context: int) -> int:
+    """One token through every layer, attending over ``context`` keys
+    (itself included): weights, LoRA and attention (scores and values).
+    A multiply-add is two operations."""
+    m = dims(cfg)
+    lora = sum(2 * m["r"] * (i + o) for i, o in lora_modules(cfg).values())
+    attn = 4 * context * m["h"] * m["hd"]
+    return m["L"] * (2 * layer_weight_params(cfg) + lora + attn)
 
 
 def _layer(key: jax.Array, cfg: Dict) -> Dict:
@@ -101,6 +184,7 @@ def _layer(key: jax.Array, cfg: Dict) -> Dict:
 
 def make_params(cfg: Dict, seed: int) -> Dict:
     """Every base weight, bf16, on the default device, in one jitted call."""
+    check(cfg)
     m = dims(cfg)
     emb_std = LOGIT_STD / math.sqrt(m["d"])
 
@@ -123,7 +207,7 @@ def adapter_fn(cfg: Dict, seed: int):
     """A jitted ``adapter_id -> adapter`` for this seed: one compile, any
     id. Leaves are bf16, A [L, r, d_in] and B [L, d_out, r]."""
     m = dims(cfg)
-    shapes = lora_shapes(cfg)
+    shapes = lora_modules(cfg)
     base = key_from(seed, SALT_ADAPTERS)
 
     def build(adapter_id: jax.Array) -> Dict:
@@ -259,6 +343,7 @@ def forward_logits(cfg: Dict, params: Dict, adapters: Dict[int, Dict],
     never sees it), one layer at a time in float32 from the bf16 weights.
     Returns one [n_out, vocab] float32 array per row.
     """
+    check(cfg)
     m = dims(cfg)
     n = len(rows)
     s = max(len(r["tokens"]) for r in rows)
@@ -275,7 +360,7 @@ def forward_logits(cfg: Dict, params: Dict, adapters: Dict[int, Dict],
         for layer in range(m["L"]):
             lp = f32(jax.tree.map(lambda a: a[layer], stacked))
             la = {}
-            for name in lora_shapes(cfg):
+            for name in lora_modules(cfg):
                 la[name] = {
                     ab: jnp.stack([adapters[r["adapter"]]["layers"]["slot0"]
                                    [name][ab][layer] for r in rows]
